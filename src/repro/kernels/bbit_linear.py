@@ -7,9 +7,9 @@ storage the method just saved.  These kernels compute
     fwd:  logits[n, c] = Σ_j  W[j, codes[n, j], c]
     bwd:  dW[j, v, c]  = Σ_n 1{codes[n, j] = v} · dout[n, c]
 
-by building the one-hot tile *in VMEM registers* (a lane-iota compare)
-and contracting it on the MXU against the (2^b, C) weight slab of each
-hash function.  The expansion never touches HBM.
+by building the one-hot tile *in VMEM registers* (an iota compare) and
+contracting it on the MXU against the (C, 2^b) weight slab of each hash
+function.  The expansion never touches HBM.
 
 Two input formats share the one-hot contraction:
 
@@ -27,6 +27,16 @@ Two input formats share the one-hot contraction:
     b ∈ {1, 2, 4, 8} so codes never straddle bytes (other b fall back
     to the XLA unpack path — see ops.py).
 
+Layout: rows sit on the 128 lanes and hash functions on sublanes.  The
+wrappers hand the kernels the codes (or packed bytes) transposed,
+``(k, n)``, and the table as ``(k, C, V)``, so one hash function's codes
+are a (1, BN) row read at a dynamic sublane offset inside a
+``fori_loop``, and its one-hot is a (V, BN) sublane-iota compare.  Every
+block then meets the TPU rule that its last two dimensions are whole
+(8, 128) tiles or the whole array.  The contraction order is that of a
+loop over hash functions, so the packed and widened kernels agree bit
+for bit.
+
 TPU-adaptive dispatch (see ops.py): for 2^b ≤ 4096 the streamed
 one-hot·W matmul reads the whole table at HBM line rate and wins; for
 b = 16 the 2^b·k·C table stream dominates and ops.py falls back to
@@ -40,40 +50,174 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _round_up(x: int, mult: int) -> int:
+    return ((x + mult - 1) // mult) * mult
+
+
+def _onehot_t(code_row, v: int, dtype):
+    """(1, BN) int32 codes → (V, BN) one-hot; a code ≥ V (the empty-bin
+    sentinel) gives an all-zero column."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (v, code_row.shape[1]), 0)
+    return (code_row == iota).astype(dtype)
+
+
+def _blocks(n: int, k: int, block_n: int, block_j: int):
+    """(BN, BJ, padded k): BN rows on lanes (all of them, or a multiple
+    of 128), BJ hash functions on sublanes (all of k, or ``block_j``)."""
+    bn = n if n <= block_n else block_n
+    k8 = _round_up(k, 8)
+    bj = k8 if k8 <= block_j else block_j
+    return bn, bj, _round_up(k, bj)
 
 
 # ---------------------------------------------------------------------------
-# Forward
+# Kernels.  ``row_fn(jj)`` yields hash function jj's (1, BN) int32 codes
+# for the current block; the widened and packed kernels differ only in it.
 # ---------------------------------------------------------------------------
-def _fwd_kernel(codes_ref, w_ref, out_ref):
-    """Grid (n/BN, k/BJ): accumulate over hash-function blocks (dim 1)."""
+def _fwd_body(row_fn, w_ref, out_ref, bj: int):
+    """out (C, BN) += Σ_jj W[jj]ᵀ (C, V) · onehot(jj) (V, BN)."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    codes = codes_ref[...]                          # (BN, BJ) int32
-    w = w_ref[...]                                  # (BJ, V, C)
-    bn, bj = codes.shape
-    v = w.shape[1]
+    v = w_ref.shape[2]
 
-    acc = out_ref[...]
-    # One-hot contraction per hash fn in the block: (BN, V) @ (V, C).
-    # BJ is kept small (the weight slab BJ·V·C dominates VMEM), so this
-    # unrolled loop stays short while each matmul feeds the MXU a
-    # (BN × V)·(V × C) contraction with V = 2^b ∈ {2..4096}.
-    for jj in range(bj):
-        onehot = (codes[:, jj][:, None]
-                  == jax.lax.broadcasted_iota(jnp.int32, (bn, v), 1))
-        acc = acc + jax.lax.dot_general(
-            onehot.astype(w.dtype), w[jj],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-    out_ref[...] = acc
+    def step(jj, acc):
+        w = w_ref[jj]                                   # (C, V)
+        onehot = _onehot_t(row_fn(jj), v, w.dtype)      # (V, BN)
+        return acc + jax.lax.dot_general(
+            w, onehot, (((1,), (0,)), ((), ())),
+            precision=_HIGHEST, preferred_element_type=jnp.float32)
+
+    out_ref[...] = jax.lax.fori_loop(0, bj, step, out_ref[...])
 
 
+def _bwd_body(row_fn, dout_ref, dw_ref, bj: int):
+    """dW[jj]ᵀ (C, V) += (onehot(jj)ᵀ (V, BN) · dout (BN, C))ᵀ.  The one-hot
+    is built column-wise from the transposed code row so that the
+    contraction over rows has the same form as ``ref.bbit_linear_bwd_dw``."""
+    i = pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _init():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    dout = dout_ref[...]                                # (BN, C)
+    v = dw_ref.shape[2]
+
+    def step(jj, carry):
+        col = row_fn(jj).T                              # (BN, 1)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (col.shape[0], v), 1)
+        onehot = (col == iota).astype(dout.dtype)       # (BN, V)
+        dw_ref[jj] = dw_ref[jj] + jax.lax.dot_general(
+            onehot, dout, (((0,), (0,)), ((), ())),
+            precision=_HIGHEST, preferred_element_type=jnp.float32).T
+        return carry
+
+    jax.lax.fori_loop(0, bj, step, 0)
+
+
+def _widened_kernel(body, bj: int):
+    def kernel(codes_ref, x_ref, o_ref):
+        body(lambda jj: codes_ref[pl.ds(jj, 1), :], x_ref, o_ref, bj)
+    return kernel
+
+
+def _packed_kernel(body, bj: int, bits: int, masked: bool):
+    """Unpacks code jj from byte jj // r (r = 8/b codes per byte, code t
+    at bit t·b, LSB-first); a marked empty bin (packbits: bit 7 − jj % 8
+    of mask byte jj // 8) becomes the never-matching code 2^b."""
+    r = 8 // bits
+    lo = (1 << bits) - 1
+
+    def kernel(*refs):
+        if masked:
+            pk_ref, em_ref, x_ref, o_ref, pk_s, em_s = refs
+            em_s[...] = em_ref[...].astype(jnp.int32)
+        else:
+            pk_ref, x_ref, o_ref, pk_s = refs
+        pk_s[...] = pk_ref[...].astype(jnp.int32)
+
+        def row(jj):
+            code = pk_s[pl.ds(jj // r, 1), :]
+            if r > 1:
+                code = (code >> ((jj % r) * bits)) & lo
+            if masked:
+                e = (em_s[pl.ds(jj // 8, 1), :] >> (7 - jj % 8)) & 1
+                code = jnp.where(e != 0, lo + 1, code)
+            return code
+
+        body(row, x_ref, o_ref, bj)
+    return kernel
+
+
+# ---------------------------------------------------------------------------
+# pallas_call plumbing shared by the four entry points
+# ---------------------------------------------------------------------------
+def _pad_rows_t(x, np_: int):
+    """(n, w) → (w, np_): transposed, zero rows appended."""
+    return jnp.pad(x, ((0, np_ - x.shape[0]), (0, 0))).T
+
+
+def _call_fwd(kernel, row_inputs, row_specs, weights, bn, bj, kp,
+              scratch, interpret):
+    """Grid (n/BN, kp/BJ): accumulate over hash-function blocks (dim 1)."""
+    k, v, c = weights.shape
+    np_ = row_inputs[0].shape[1]
+    w_t = jnp.pad(weights, ((0, kp - k), (0, 0), (0, 0))).transpose(0, 2, 1)
+    out = pl.pallas_call(
+        kernel,
+        grid=(np_ // bn, kp // bj),
+        in_specs=row_specs(lambda i, j: (j, i))
+        + [pl.BlockSpec((bj, c, v), lambda i, j: (j, 0, 0))],
+        out_specs=pl.BlockSpec((c, bn), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((c, np_), jnp.float32),
+        scratch_shapes=scratch,
+        interpret=interpret,
+    )(*row_inputs, w_t)
+    return out.T
+
+
+def _call_bwd(kernel, row_inputs, row_specs, dout, n, vsize, bn, bj, kp,
+              scratch, interpret):
+    """Grid (kp/BJ, n/BN): accumulate over example blocks (dim 1).
+    Padded examples carry zero dout → no effect."""
+    c = dout.shape[1]
+    np_ = row_inputs[0].shape[1]
+    dout_p = jnp.pad(dout.astype(jnp.float32), ((0, np_ - n), (0, 0)))
+    dw_t = pl.pallas_call(
+        kernel,
+        grid=(kp // bj, np_ // bn),
+        in_specs=row_specs(lambda j, i: (j, i))
+        + [pl.BlockSpec((bn, c), lambda j, i: (i, 0))],
+        out_specs=pl.BlockSpec((bj, c, vsize), lambda j, i: (j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((kp, c, vsize), jnp.float32),
+        scratch_shapes=scratch,
+        interpret=interpret,
+    )(*row_inputs, dout_p)
+    return dw_t.transpose(0, 2, 1)
+
+
+def _widened_inputs(codes, bn, bj, kp):
+    n, k = codes.shape
+    np_ = _round_up(n, bn)
+    codes_t = _pad_rows_t(jnp.pad(codes, ((0, 0), (0, kp - k))), np_)
+
+    def specs(index):
+        return [pl.BlockSpec((bj, bn), index)]
+    return [codes_t], specs
+
+
+# ---------------------------------------------------------------------------
+# Widened int32 codes
+# ---------------------------------------------------------------------------
 @functools.partial(
     jax.jit, static_argnames=("block_n", "block_j", "interpret")
 )
@@ -85,60 +229,16 @@ def bbit_linear_fwd_pallas(
     block_j: int = 8,
     interpret: bool = False,
 ) -> jax.Array:
-    """logits (n, C) f32 from codes (n, k) int32 and W (k, V, C)."""
+    """logits (n, C) f32 from codes (n, k) int32 and W (k, V, C).
+
+    Padded hash functions point at code 0 of a zero weight row and
+    padded examples are sliced away, so padding adds exactly nothing."""
     n, k = codes.shape
-    _, v, c = weights.shape
-    bn = min(block_n, n)
-    bj = min(block_j, k)
-
-    pad_n = (-n) % bn
-    pad_k = (-k) % bj
-    codes_p = jnp.pad(codes, ((0, pad_n), (0, pad_k)))
-    w_p = jnp.pad(weights, ((0, pad_k), (0, 0), (0, 0)))
-    np_, kp_ = codes_p.shape
-
-    out = pl.pallas_call(
-        _fwd_kernel,
-        grid=(np_ // bn, kp_ // bj),
-        in_specs=[
-            pl.BlockSpec((bn, bj), lambda i, j: (i, j)),
-            pl.BlockSpec((bj, v, c), lambda i, j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bn, c), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((np_, c), jnp.float32),
-        interpret=interpret,
-    )(codes_p, w_p)
+    bn, bj, kp = _blocks(n, k, block_n, block_j)
+    inputs, specs = _widened_inputs(codes, bn, bj, kp)
+    out = _call_fwd(_widened_kernel(_fwd_body, bj), inputs, specs,
+                    weights, bn, bj, kp, [], interpret)
     return out[:n]
-
-
-# ---------------------------------------------------------------------------
-# Backward: dW (the dcodes gradient does not exist — codes are integers)
-# ---------------------------------------------------------------------------
-def _bwd_kernel(codes_ref, dout_ref, dw_ref):
-    """Grid (k/BJ, n/BN): accumulate over example blocks (dim 1)."""
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        dw_ref[...] = jnp.zeros_like(dw_ref)
-
-    codes = codes_ref[...]                          # (BN, BJ)
-    dout = dout_ref[...]                            # (BN, C)
-    bn, bj = codes.shape
-    v = dw_ref.shape[1]
-
-    acc = dw_ref[...]
-    for jj in range(bj):
-        onehot = (codes[:, jj][:, None]
-                  == jax.lax.broadcasted_iota(jnp.int32, (bn, v), 1))
-        # (V, BN) @ (BN, C) on the MXU.
-        contrib = jax.lax.dot_general(
-            onehot.astype(dout.dtype), dout,
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc = acc.at[jj].add(contrib)
-    dw_ref[...] = acc
 
 
 @functools.partial(
@@ -155,29 +255,10 @@ def bbit_linear_bwd_dw_pallas(
 ) -> jax.Array:
     """dW (k, V, C) f32 from codes (n, k) and dout (n, C)."""
     n, k = codes.shape
-    c = dout.shape[1]
-    bn = min(block_n, n)
-    bj = min(block_j, k)
-
-    pad_n = (-n) % bn
-    pad_k = (-k) % bj
-    # Padded examples point at code 0 but carry zero dout → no effect;
-    # padded hash fns produce rows sliced away below.
-    codes_p = jnp.pad(codes, ((0, pad_n), (0, pad_k)))
-    dout_p = jnp.pad(dout, ((0, pad_n), (0, 0)))
-    np_, kp_ = codes_p.shape
-
-    dw = pl.pallas_call(
-        _bwd_kernel,
-        grid=(kp_ // bj, np_ // bn),
-        in_specs=[
-            pl.BlockSpec((bn, bj), lambda j, i: (i, j)),
-            pl.BlockSpec((bn, c), lambda j, i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bj, vsize, c), lambda j, i: (j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((kp_, vsize, c), jnp.float32),
-        interpret=interpret,
-    )(codes_p, dout_p)
+    bn, bj, kp = _blocks(n, k, block_n, block_j)
+    inputs, specs = _widened_inputs(codes, bn, bj, kp)
+    dw = _call_bwd(_widened_kernel(_bwd_body, bj), inputs, specs, dout,
+                   n, vsize, bn, bj, kp, [], interpret)
     return dw[:k]
 
 
@@ -187,87 +268,41 @@ def bbit_linear_bwd_dw_pallas(
 # bitstream, LSB-first: code j·(8/b)+t sits in byte j at bit offset t·b)
 # and np.packbits (MSB-first) for the empty bitmask.
 # ---------------------------------------------------------------------------
-def _unpack_codes_block(pk, bits: int):
-    """(BN, WB) uint8 packed block → (BN, WB·8/b) int32 codes."""
-    r = 8 // bits
-    p = pk.astype(jnp.uint32)
-    mask = jnp.uint32((1 << bits) - 1)
-    cols = jnp.stack(
-        [(p >> jnp.uint32(t * bits)) & mask for t in range(r)], axis=2)
-    return cols.reshape(pk.shape[0], -1).astype(jnp.int32)
-
-
-def _unpack_mask_block(em):
-    """(BN, EB) uint8 packbits block → (BN, EB·8) bool (MSB-first)."""
-    p = em.astype(jnp.uint32)
-    cols = jnp.stack(
-        [(p >> jnp.uint32(7 - t)) & 1 for t in range(8)], axis=2)
-    return cols.reshape(em.shape[0], -1) != 0
-
-
-def _make_packed_fwd_kernel(bits: int, masked: bool):
-    def kernel(pk_ref, *rest):
-        if masked:
-            em_ref, w_ref, out_ref = rest
-        else:
-            w_ref, out_ref = rest
-        j = pl.program_id(1)
-
-        @pl.when(j == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        codes = _unpack_codes_block(pk_ref[...], bits)   # (BN, BJ) int32
-        empty = _unpack_mask_block(em_ref[...]) if masked else None
-        w = w_ref[...]                                   # (BJ, V, C)
-        bn, bj = codes.shape
-        v = w.shape[1]
-
-        acc = out_ref[...]
-        for jj in range(bj):
-            onehot = (codes[:, jj][:, None]
-                      == jax.lax.broadcasted_iota(jnp.int32, (bn, v), 1))
-            if masked:
-                onehot = onehot & ~empty[:, jj][:, None]
-            acc = acc + jax.lax.dot_general(
-                onehot.astype(w.dtype), w[jj],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        out_ref[...] = acc
-    return kernel
-
-
-def _packed_blocks(n, k, bits, block_n, block_j):
-    """Shared block geometry: BJ is a multiple of 8 so one code block is
-    a whole number of packed bytes AND a whole number of mask bytes."""
-    bj = min(block_j, ((k + 7) // 8) * 8)
-    bj = ((bj + 7) // 8) * 8
-    bn = min(block_n, n)
-    kp = ((k + bj - 1) // bj) * bj
-    return bn, bj, kp
-
-
-def _pad_packed_inputs(packed, empty, weights, k, bits, bn, bj, kp):
-    """Pads rows to a BN multiple and the k axis to a BJ multiple.
-
-    Padding bytes unpack to code 0 and padded weight rows are zero, so
-    padded lanes contribute exactly nothing — this is what makes
-    non-lane-multiple k (and the pack format's own zero padding bits in
-    the final byte) exact rather than approximately masked.
-    """
+def _packed_inputs(packed, empty, k, bits, bn, bj, kp):
+    """Transposed byte (and mask) rows, padded to kp hash functions and
+    a BN multiple of rows.  Padding bytes unpack to code 0 of a zero
+    weight row, so non-lane-multiple k (and the pack format's own zero
+    padding bits in the final byte) is exact rather than masked."""
     n = packed.shape[0]
-    pad_n = (-n) % bn
-    wp = kp * bits // 8
-    packed_p = jnp.pad(packed,
-                       ((0, pad_n), (0, wp - packed.shape[1])))
-    w_p = jnp.pad(weights, ((0, kp - k), (0, 0), (0, 0)))
-    empty_p = None
+    np_ = _round_up(n, bn)
+    wb = bj * bits // 8
+    pk_t = _pad_rows_t(
+        jnp.pad(packed, ((0, 0), (0, kp * bits // 8 - packed.shape[1]))),
+        np_)
+    inputs = [pk_t]
+    scratch = [pltpu.VMEM((wb, bn), jnp.int32)]
     if empty is not None:
-        ep = kp // 8
-        empty_p = jnp.pad(empty,
-                          ((0, pad_n), (0, ep - empty.shape[1])))
-    return packed_p, empty_p, w_p
+        inputs.append(_pad_rows_t(
+            jnp.pad(empty, ((0, 0), (0, kp // 8 - empty.shape[1]))), np_))
+        scratch.append(pltpu.VMEM((bj // 8, bn), jnp.int32))
+
+    def specs(index):
+        out = [pl.BlockSpec((wb, bn), index)]
+        if empty is not None:
+            out.append(pl.BlockSpec((bj // 8, bn), index))
+        return out
+    return inputs, specs, scratch
+
+
+def _packed_blocks(n: int, k: int, block_n: int, block_j: int):
+    """A hash-function block spans whole packed bytes and mask bytes:
+    BJ is all of k (rounded to 8), or ``block_j``, a multiple of 256, so
+    that its BJ·b/8 byte rows and BJ/8 mask rows fill whole (32, 128)
+    uint8 tiles."""
+    if block_j % 256:
+        raise ValueError(f"packed block_j must be a multiple of 256, "
+                         f"got {block_j}")
+    return _blocks(n, k, block_n, block_j)
 
 
 @functools.partial(
@@ -282,77 +317,25 @@ def bbit_linear_packed_fwd_pallas(
     bits: int,
     empty: jax.Array = None,
     block_n: int = 128,
-    block_j: int = 8,
+    block_j: int = 256,
     interpret: bool = False,
 ) -> jax.Array:
     """logits (n, C) f32 straight from packed uint8 (n, ceil(k·bits/8)).
 
-    Bit-exact vs ``unpack_codes_jnp`` + the widened kernel/gather
+    Bit-exact vs ``unpack_codes_jnp`` + the widened kernel
     (tests/test_packed_linear.py property-sweeps b, ragged masks and
     non-lane-multiple k).  ``empty`` (uint8 (n, ceil(k/8)), packbits
     layout) drops the marked bins — the ``oph_zero`` ragged-mask path,
     fused here instead of falling back to an XLA gather.
     """
     n = packed.shape[0]
-    _, v, c = weights.shape
-    bn, bj, kp = _packed_blocks(n, k, bits, block_n, block_j)
-    packed_p, empty_p, w_p = _pad_packed_inputs(
-        packed, empty, weights, k, bits, bn, bj, kp)
-    np_ = packed_p.shape[0]
-    wb = bj * bits // 8
-
-    masked = empty is not None
-    in_specs = [pl.BlockSpec((bn, wb), lambda i, j: (i, j))]
-    args = [packed_p]
-    if masked:
-        in_specs.append(pl.BlockSpec((bn, bj // 8), lambda i, j: (i, j)))
-        args.append(empty_p)
-    in_specs.append(pl.BlockSpec((bj, v, c), lambda i, j: (j, 0, 0)))
-    args.append(w_p)
-
-    out = pl.pallas_call(
-        _make_packed_fwd_kernel(bits, masked),
-        grid=(np_ // bn, kp // bj),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bn, c), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((np_, c), jnp.float32),
-        interpret=interpret,
-    )(*args)
+    bn, bj, kp = _packed_blocks(n, k, block_n, block_j)
+    inputs, specs, scratch = _packed_inputs(packed, empty, k, bits,
+                                            bn, bj, kp)
+    kernel = _packed_kernel(_fwd_body, bj, bits, empty is not None)
+    out = _call_fwd(kernel, inputs, specs, weights, bn, bj, kp, scratch,
+                    interpret)
     return out[:n]
-
-
-def _make_packed_bwd_kernel(bits: int, masked: bool):
-    def kernel(pk_ref, *rest):
-        if masked:
-            em_ref, dout_ref, dw_ref = rest
-        else:
-            dout_ref, dw_ref = rest
-        i = pl.program_id(1)
-
-        @pl.when(i == 0)
-        def _init():
-            dw_ref[...] = jnp.zeros_like(dw_ref)
-
-        codes = _unpack_codes_block(pk_ref[...], bits)   # (BN, BJ)
-        empty = _unpack_mask_block(em_ref[...]) if masked else None
-        dout = dout_ref[...]                             # (BN, C)
-        bn, bj = codes.shape
-        v = dw_ref.shape[1]
-
-        acc = dw_ref[...]
-        for jj in range(bj):
-            onehot = (codes[:, jj][:, None]
-                      == jax.lax.broadcasted_iota(jnp.int32, (bn, v), 1))
-            if masked:
-                onehot = onehot & ~empty[:, jj][:, None]
-            contrib = jax.lax.dot_general(
-                onehot.astype(dout.dtype), dout,
-                (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            acc = acc.at[jj].add(contrib)
-        dw_ref[...] = acc
-    return kernel
 
 
 @functools.partial(
@@ -369,38 +352,17 @@ def bbit_linear_packed_bwd_dw_pallas(
     bits: int,
     empty: jax.Array = None,
     block_n: int = 128,
-    block_j: int = 8,
+    block_j: int = 256,
     interpret: bool = False,
 ) -> jax.Array:
     """dW (k, V, C) f32 from packed rows and dout (n, C), in-register
     unpack; ``empty`` bins contribute nothing (their one-hot row is
     zeroed, matching the forward)."""
     n = packed.shape[0]
-    c = dout.shape[1]
-    bn, bj, kp = _packed_blocks(n, k, bits, block_n, block_j)
-    packed_p, empty_p, _w = _pad_packed_inputs(
-        packed, empty, jnp.zeros((k, vsize, c), jnp.float32),
-        k, bits, bn, bj, kp)
-    np_ = packed_p.shape[0]
-    # Padded examples unpack to code 0 but carry zero dout → no effect.
-    dout_p = jnp.pad(dout.astype(jnp.float32), ((0, np_ - n), (0, 0)))
-    wb = bj * bits // 8
-
-    masked = empty is not None
-    in_specs = [pl.BlockSpec((bn, wb), lambda j, i: (i, j))]
-    args = [packed_p]
-    if masked:
-        in_specs.append(pl.BlockSpec((bn, bj // 8), lambda j, i: (i, j)))
-        args.append(empty_p)
-    in_specs.append(pl.BlockSpec((bn, c), lambda j, i: (i, 0)))
-    args.append(dout_p)
-
-    dw = pl.pallas_call(
-        _make_packed_bwd_kernel(bits, masked),
-        grid=(kp // bj, np_ // bn),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bj, vsize, c), lambda j, i: (j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((kp, vsize, c), jnp.float32),
-        interpret=interpret,
-    )(*args)
+    bn, bj, kp = _packed_blocks(n, k, block_n, block_j)
+    inputs, specs, scratch = _packed_inputs(packed, empty, k, bits,
+                                            bn, bj, kp)
+    kernel = _packed_kernel(_bwd_body, bj, bits, empty is not None)
+    dw = _call_bwd(kernel, inputs, specs, dout, n, vsize, bn, bj, kp,
+                   scratch, interpret)
     return dw[:k]

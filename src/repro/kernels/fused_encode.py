@@ -24,16 +24,17 @@ additionally packs the empty-bin bitmask MSB-first — the
 ``np.packbits`` layout the shard format stores.
 
 In-kernel densification mirrors ``core.oph.densify_rotation``: the
-next-non-empty-bin search is a reverse cummin over doubled (circular)
-lanes, and the borrow gather is lane-broadcast compare-select — the
-same VPU-style trick as the scatter-min — since a true gather is
-TPU-hostile.  O(k²) selects per row, done ONCE per row versus O(k·nnz)
-work in the main loop.
+next non-empty bin (circularly) and its minimum are found together by
+log2(k) lane-rotate-and-select doubling steps, since neither a gather
+nor a cumulative min lowers on Mosaic.  O(k·log k) work per row, done
+ONCE per row versus O(k·nnz) in the main loop.
 
-Layout caveat: packed output rows are ceil(k·b/8) bytes, which for
-small k·b is narrower than the 128-lane tile; interpret mode (CPU CI)
-is exact for any shape, while a compiled TPU deployment should keep
-k·b ≥ 1024 (e.g. k=256, b≥4) or accept lane padding.
+Mosaic has no strided lane slice either, so bytes are packed by one
+exact bf16 matmul against a constant place-value matrix (every operand
+and partial sum is an integer below 256).  Minima are kept as ordered
+int32 (``minhash._ordered``): Mosaic has no unsigned min.  Per-row
+``nnz`` is a (BN, 1) block and the minwise parameters (1, BK) blocks,
+the layouts the TPU block rules accept.
 """
 from __future__ import annotations
 
@@ -44,7 +45,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.minhash import _fmix32
+from repro.kernels.minhash import (
+    ORDERED_MAX,
+    _minhash_block_min,
+    _unordered,
+)
+from repro.kernels.oph import _oph_block_min
 
 PACK_BITS = (1, 2, 4, 8)   # b where codes never straddle byte bounds
 
@@ -62,26 +68,29 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
-def _pack_lanes(codes, width: int, bits: int):
-    """(bn, L) uint32 codes < 2^bits → (bn, width) uint8, LSB-first.
+def _pack_lanes(vals, width: int, bits: int, *, msb_first: bool = False):
+    """(BN, width·(8/bits)) ints < 2^bits → (BN, width) uint8.
 
-    L must equal width·(8/bits); lanes beyond the logical k are expected
-    to be zeroed by the caller so padding bits match ``pack_codes``.
+    Byte j holds lanes j·r … j·r+r−1 (r = 8/bits): lane t at bit t·bits
+    (LSB-first, ``pack_codes``) or, for bit masks, at bit 7−t
+    (MSB-first, ``np.packbits``).  Lanes beyond the logical k must be
+    zeroed by the caller so padding bits match the references.
     """
+    v = vals.astype(jnp.int32)
     r = 8 // bits
-    packed = jnp.zeros((codes.shape[0], width), jnp.uint32)
-    for t in range(r):
-        packed = packed | (codes[:, t::r] << jnp.uint32(t * bits))
-    return packed.astype(jnp.uint8)
-
-
-def _pack_mask_lanes(mask, width: int):
-    """(bn, width·8) bool → (bn, width) uint8, MSB-first (packbits)."""
-    packed = jnp.zeros((mask.shape[0], width), jnp.uint32)
-    for t in range(8):
-        packed = packed | (mask[:, t::8].astype(jnp.uint32)
-                           << jnp.uint32(7 - t))
-    return packed.astype(jnp.uint8)
+    if r == 1:
+        return v.astype(jnp.uint8)
+    lanes = width * r
+    lane = jax.lax.broadcasted_iota(jnp.int32, (lanes, width), 0)
+    byte = jax.lax.broadcasted_iota(jnp.int32, (lanes, width), 1)
+    t = lane & (r - 1)
+    shift = (7 - t) if msb_first else t * bits
+    place = jnp.where((lane >> (r.bit_length() - 1)) == byte,
+                      jnp.left_shift(jnp.ones_like(shift), shift), 0)
+    packed = jnp.dot(v.astype(jnp.float32).astype(jnp.bfloat16),
+                     place.astype(jnp.float32).astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32)
+    return packed.astype(jnp.int32).astype(jnp.uint8)
 
 
 # ---------------------------------------------------------------------------
@@ -96,26 +105,19 @@ def _minhash_pack_kernel(idx_ref, nnz_ref, a_ref, b_ref, out_ref, acc_ref, *,
     """
     j = pl.program_id(1)
     c = pl.program_id(2)
-    sentinel = jnp.uint32(0xFFFFFFFF)
 
     @pl.when(c == 0)
     def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, sentinel)
+        acc_ref[...] = jnp.full_like(acc_ref, ORDERED_MAX)
 
-    idx = idx_ref[...].astype(jnp.uint32)            # (BN, MC)
-    nnz = nnz_ref[...]                               # (BN,)
-    a = a_ref[...]                                   # (BK,)
-    b = b_ref[...]                                   # (BK,)
-    bn = idx.shape[0]
-    col = c * mc + jax.lax.broadcasted_iota(jnp.int32, (bn, mc), 1)
-    valid = col < nnz[:, None]                       # (BN, MC)
-    h = _fmix32(a[None, None, :] * idx[:, :, None] + b[None, None, :])
-    h = jnp.where(valid[:, :, None], h, sentinel)    # (BN, MC, BK)
-    acc_ref[...] = jnp.minimum(acc_ref[...], jnp.min(h, axis=1))
+    acc_ref[...] = jnp.minimum(
+        acc_ref[...], _minhash_block_min(idx_ref, nnz_ref, a_ref, b_ref,
+                                         c, mc))
 
     @pl.when(c == nc - 1)
     def _finish():
-        codes = acc_ref[...] & jnp.uint32((1 << bits) - 1)
+        codes = _unordered(acc_ref[...]) & jnp.uint32((1 << bits) - 1)
+        bn = codes.shape[0]
         lane = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bn, bk), 1)
         codes = jnp.where(lane < k, codes, jnp.uint32(0))
         out_ref[...] = _pack_lanes(codes, bk * bits // 8, bits)
@@ -155,6 +157,10 @@ def minhash_pack_pallas(
     bn = min(block_n, n)
     # hash-block must be a multiple of 8 so each out byte is intra-block
     bk = _round_up(min(block_k, _round_up(k, 8)), 8)
+    if bk < k and (bk * bits // 8) % 128:
+        # a hash-block's packed bytes would not fill whole 128-lane
+        # tiles: keep every hash function in one block instead
+        bk = _round_up(k, 8)
     mc = min(block_m, m)
 
     def _pad_to(x, mult, axis, value):
@@ -166,11 +172,11 @@ def minhash_pack_pallas(
         return jnp.pad(x, widths, constant_values=value)
 
     idx_p = _pad_to(_pad_to(indices, bn, 0, 0), mc, 1, 0)
-    nnz_p = _pad_to(nnz, bn, 0, 0)
-    a_p = _pad_to(a, bk, 0, jnp.uint32(1))
-    b_p = _pad_to(b, bk, 0, jnp.uint32(0))
+    nnz_p = _pad_to(nnz, bn, 0, 0).reshape(-1, 1)
+    a_p = _pad_to(a, bk, 0, jnp.uint32(1)).reshape(1, -1)
+    b_p = _pad_to(b, bk, 0, jnp.uint32(0)).reshape(1, -1)
     np_, mp_ = idx_p.shape
-    kp_ = a_p.shape[0]
+    kp_ = a_p.shape[1]
     nc = mp_ // mc
     ob = bk * bits // 8                   # packed bytes per hash-block
 
@@ -181,13 +187,13 @@ def minhash_pack_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, mc), lambda i, j, c: (i, c)),
-            pl.BlockSpec((bn,), lambda i, j, c: (i,)),
-            pl.BlockSpec((bk,), lambda i, j, c: (j,)),
-            pl.BlockSpec((bk,), lambda i, j, c: (j,)),
+            pl.BlockSpec((bn, 1), lambda i, j, c: (i, 0)),
+            pl.BlockSpec((1, bk), lambda i, j, c: (0, j)),
+            pl.BlockSpec((1, bk), lambda i, j, c: (0, j)),
         ],
         out_specs=pl.BlockSpec((bn, ob), lambda i, j, c: (i, j)),
         out_shape=jax.ShapeDtypeStruct((np_, kp_ * bits // 8), jnp.uint8),
-        scratch_shapes=[pltpu.VMEM((bn, bk), jnp.uint32)],
+        scratch_shapes=[pltpu.VMEM((bn, bk), jnp.int32)],
         interpret=interpret,
     )(idx_p, nnz_p, a_p, b_p)
     return out[:n, :(k * bits + 7) // 8]
@@ -196,71 +202,75 @@ def minhash_pack_pallas(
 # ---------------------------------------------------------------------------
 # Fused OPH: bin minima → densify/zero-code → b-bit → packed bytes.
 # ---------------------------------------------------------------------------
+def _densify_rotation(vk, k: int):
+    """``core.oph.densify_rotation`` on ordered (BN, K) minima: each
+    empty bin borrows the minimum of the next non-empty bin j+d
+    (circularly), offset by d·_ROT_C.  Rows with no non-empty bin stay
+    at the 0xFFFFFFFF sentinel.
+
+    Doubling: after the step of size s every lane holds the nearest
+    non-empty bin among offsets [0, 2s); distances are distinct, so the
+    strict compare picks exactly the bin the reference picks.
+
+    Every value that reaches a ``pltpu.roll`` is built by integer
+    arithmetic, not by a select: on a v5e chip Mosaic returned zeros
+    for the roll of ``where(empty, 1 << 20, 0)`` and of
+    ``empty.astype(int32) << 20``.
+    """
+    x = vk ^ ORDERED_MAX                            # 0 only on empty bins
+    # k (farther than any bin) on empty bins, else 0
+    dist = (((x | -x) >> 31) + 1) << (k.bit_length() - 1)
+    val = vk
+    s = 1
+    while s < k:
+        # roll by k − s brings lane j+s (mod k) to lane j
+        d2 = pltpu.roll(dist, k - s, 1) + s
+        v2 = pltpu.roll(val, k - s, 1)
+        take = ((d2 - dist) >> 31) & 1              # 1 where d2 < dist
+        dist = jnp.minimum(d2, dist)
+        val = val ^ ((val ^ v2) & -take)
+        s *= 2
+    borrowed = _unordered(val) + dist.astype(jnp.uint32) * jnp.uint32(_ROT_C)
+    return jnp.where(dist >= k, jnp.full_like(borrowed, 0xFFFFFFFF),
+                     borrowed)
+
+
 def _oph_pack_kernel(a_ref, b_ref, idx_ref, nnz_ref, out_ref, eout_ref,
                      acc_ref, *, mc: int, shift: int, k: int, kp: int,
                      bits: int, densify: bool, nc: int, ow: int, ew: int):
     """One (doc-block, nnz-block) grid step: hash once, min-scatter into
     scratch; densify + pack on the final step."""
     c = pl.program_id(1)
-    sentinel = jnp.uint32(0xFFFFFFFF)
 
     @pl.when(c == 0)
     def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, sentinel)
+        acc_ref[...] = jnp.full_like(acc_ref, ORDERED_MAX)
 
-    idx = idx_ref[...].astype(jnp.uint32)            # (BN, MC)
-    nnz = nnz_ref[...]                               # (BN,)
-    bn = idx.shape[0]
-    col = c * mc + jax.lax.broadcasted_iota(jnp.int32, (bn, mc), 1)
-    valid = col < nnz[:, None]
-
-    h = _fmix32(a_ref[0, 0] * idx + b_ref[0, 0])     # ONE hash per nonzero
-    bins = (h >> jnp.uint32(shift)).astype(jnp.int32)
-    hv = jnp.where(valid, h, sentinel)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bn, mc, kp), 2)
-    scat = jnp.where(bins[:, :, None] == lane, hv[:, :, None], sentinel)
-    acc_ref[...] = jnp.minimum(acc_ref[...], jnp.min(scat, axis=1))
+    acc_ref[...] = jnp.minimum(
+        acc_ref[...], _oph_block_min(a_ref, b_ref, idx_ref, nnz_ref, c,
+                                     mc=mc, shift=shift, kp=kp))
 
     @pl.when(c == nc - 1)
     def _finish():
-        vals = acc_ref[...]                          # (BN, KP)
+        vals = acc_ref[...]                          # (BN, KP) ordered
         vk = vals[:, :k] if kp > k else vals         # logical bins only
-        ek = vk == sentinel                          # (BN, K) empty bins
+        ek = vk == ORDERED_MAX                       # (BN, K) empty bins
+        bn = vk.shape[0]
         mask_b = jnp.uint32((1 << bits) - 1)
         if densify:
-            # next non-empty bin at-or-after j, circular: reverse cummin
-            # over doubled lanes (== core.oph.densify_rotation).
-            ne2 = jnp.concatenate([~ek, ~ek], axis=1)            # (BN, 2K)
-            iota2 = jax.lax.broadcasted_iota(jnp.int32, (bn, 2 * k), 1)
-            cand = jnp.where(ne2, iota2, jnp.int32(2 * k))
-            nxt = jax.lax.cummin(cand, axis=1, reverse=True)[:, :k]
-            iota_k = jax.lax.broadcasted_iota(jnp.int32, (bn, k), 1)
-            dist = nxt - iota_k
-            src = jnp.where(nxt < 2 * k, nxt & (k - 1), 0)
-            # borrow gather, the VPU way: broadcast-compare src against a
-            # k-lane iota and select (exactly one lane matches).
-            lane_j = jax.lax.broadcasted_iota(jnp.int32, (bn, k, k), 2)
-            borrowed = jnp.min(
-                jnp.where(src[:, :, None] == lane_j, vk[:, None, :],
-                          sentinel), axis=2)
-            borrowed = borrowed + dist.astype(jnp.uint32) * jnp.uint32(
-                _ROT_C)
-            all_empty = jnp.all(ek, axis=1, keepdims=True)
-            dense = jnp.where(all_empty | (nxt >= 2 * k), sentinel,
-                              borrowed)
-            codes = dense & mask_b    # all-empty rows → all-ones bits,
-        else:                         # matching the packed reference
-            codes = jnp.where(ek, jnp.uint32(0), vk & mask_b)
+            codes = _densify_rotation(vk, k) & mask_b
+        else:
+            codes = jnp.where(ek, jnp.uint32(0), _unordered(vk) & mask_b)
         kpad = ow * (8 // bits)
         if kpad > k:
             codes = jnp.concatenate(
                 [codes, jnp.zeros((bn, kpad - k), jnp.uint32)], axis=1)
         out_ref[...] = _pack_lanes(codes, ow, bits)
-        e = ek
+        e = ek.astype(jnp.int32)
         if ew * 8 > k:
             e = jnp.concatenate(
-                [ek, jnp.zeros((bn, ew * 8 - k), jnp.bool_)], axis=1)
-        eout_ref[...] = _pack_mask_lanes(e, ew)
+                [e, jnp.zeros((bn, ew * 8 - k), jnp.int32)], axis=1)
+        eout_ref[...] = _pack_lanes(e, ew, 1, msb_first=True)
 
 
 @functools.partial(
@@ -318,7 +328,7 @@ def oph_pack_pallas(
         return jnp.pad(x, widths)
 
     idx_p = _pad_to(_pad_to(indices, bn, 0), mc, 1)
-    nnz_p = _pad_to(nnz, bn, 0)
+    nnz_p = _pad_to(nnz, bn, 0).reshape(-1, 1)
     np_, mp_ = idx_p.shape
     nc = mp_ // mc
 
@@ -334,7 +344,7 @@ def oph_pack_pallas(
             pl.BlockSpec((1, 1), lambda i, c: (0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((bn, mc), lambda i, c: (i, c)),
-            pl.BlockSpec((bn,), lambda i, c: (i,)),
+            pl.BlockSpec((bn, 1), lambda i, c: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bn, ow), lambda i, c: (i, 0)),
@@ -344,7 +354,7 @@ def oph_pack_pallas(
             jax.ShapeDtypeStruct((np_, ow), jnp.uint8),
             jax.ShapeDtypeStruct((np_, ew), jnp.uint8),
         ],
-        scratch_shapes=[pltpu.VMEM((bn, kp), jnp.uint32)],
+        scratch_shapes=[pltpu.VMEM((bn, kp), jnp.int32)],
         interpret=interpret,
     )(a.reshape(1, 1), b.reshape(1, 1), idx_p, nnz_p)
     return packed[:n], empty[:n]

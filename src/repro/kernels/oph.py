@@ -9,7 +9,7 @@ no hash-block grid dimension at all:
   * documents  → sublane-tiled grid dim 0 (BN rows),
   * nonzeros   → grid dim 1, streamed HBM→VMEM in MC-column blocks
                  (each nonzero is read ONCE),
-  * bins       → all k live in lanes of the output block, revisited
+  * bins       → all k live in lanes of a VMEM scratch block, revisited
                  across grid dim 1 with a running min.
 
 Scatter-min into k lanes is TPU-hostile as a true scatter, so it is
@@ -27,7 +27,7 @@ fall off at the final slice.
 This kernel returns the raw uint32 minima (n·k·4 bytes to the host).
 The preprocessing hot path uses ``repro.kernels.fused_encode``'s
 ``oph_pack_pallas`` instead, which shares this kernel's grid and
-scatter-min body but densifies, b-bit-masks and byte-packs in the
+scatter-min body (``_oph_block_min``) but densifies, b-bit-masks and byte-packs in the
 final grid step so only n·ceil(k·b/8) bytes leave the device.
 """
 from __future__ import annotations
@@ -39,40 +39,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _fmix32(h):
-    h = h ^ (h >> jnp.uint32(16))
-    h = h * jnp.uint32(0x85EBCA6B)
-    h = h ^ (h >> jnp.uint32(13))
-    h = h * jnp.uint32(0xC2B2AE35)
-    h = h ^ (h >> jnp.uint32(16))
-    return h
+from repro.core.universal_hash import _fmix32
+from repro.kernels.minhash import ORDERED_MAX, _ordered, _unordered, \
+    _valid_cols
 
 
-def _oph_kernel(a_ref, b_ref, idx_ref, nnz_ref, out_ref, *,
-                mc: int, shift: int, kp: int):
+def _oph_block_min(a_ref, b_ref, idx_ref, nnz_ref, c, *, mc: int,
+                   shift: int, kp: int):
+    """Ordered (BN, KP) bin minima of one (BN, MC) nonzero block: hash
+    once, then the lane-parallel scatter-min
+    out[n, j] = min over m with bins[n, m] == j."""
+    idx = idx_ref[...].astype(jnp.uint32)            # (BN, MC)
+    bn = idx.shape[0]
+    valid = _valid_cols(nnz_ref, bn, mc, c)
+    h = _fmix32(a_ref[0, 0] * idx + b_ref[0, 0])     # ONE hash per nonzero
+    bins = (h >> jnp.uint32(shift)).astype(jnp.int32)
+    hv = jnp.where(valid, _ordered(h), jnp.int32(ORDERED_MAX))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bn, mc, kp), 2)
+    scat = jnp.where(bins[:, :, None] == lane, hv[:, :, None],
+                     jnp.int32(ORDERED_MAX))
+    return jnp.min(scat, axis=1)
+
+
+def _oph_kernel(a_ref, b_ref, idx_ref, nnz_ref, out_ref, acc_ref, *,
+                mc: int, shift: int, kp: int, nc: int):
     """One (doc-block, nnz-block) grid step: hash once, min-scatter."""
     c = pl.program_id(1)
-    sentinel = jnp.uint32(0xFFFFFFFF)
 
     @pl.when(c == 0)
     def _init():
-        out_ref[...] = jnp.full_like(out_ref, sentinel)
+        acc_ref[...] = jnp.full_like(acc_ref, ORDERED_MAX)
 
-    idx = idx_ref[...].astype(jnp.uint32)            # (BN, MC)
-    nnz = nnz_ref[...]                               # (BN,)
-    bn = idx.shape[0]
-    col = c * mc + jax.lax.broadcasted_iota(jnp.int32, (bn, mc), 1)
-    valid = col < nnz[:, None]                       # (BN, MC)
+    acc_ref[...] = jnp.minimum(
+        acc_ref[...], _oph_block_min(a_ref, b_ref, idx_ref, nnz_ref, c,
+                                     mc=mc, shift=shift, kp=kp))
 
-    h = _fmix32(a_ref[0, 0] * idx + b_ref[0, 0])     # ONE hash per nonzero
-    bins = (h >> jnp.uint32(shift)).astype(jnp.int32)
-    hv = jnp.where(valid, h, sentinel)
-
-    # lane-parallel scatter-min: out[n, j] = min over m with bins==j
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bn, mc, kp), 2)
-    scat = jnp.where(bins[:, :, None] == lane, hv[:, :, None], sentinel)
-    out_ref[...] = jnp.minimum(out_ref[...], jnp.min(scat, axis=1))
+    @pl.when(c == nc - 1)
+    def _finish():
+        out_ref[...] = _unordered(acc_ref[...])
 
 
 @functools.partial(
@@ -118,12 +122,13 @@ def oph_pallas(
         return jnp.pad(x, widths)
 
     idx_p = _pad_to(_pad_to(indices, bn, 0), mc, 1)
-    nnz_p = _pad_to(nnz, bn, 0)
+    nnz_p = _pad_to(nnz, bn, 0).reshape(-1, 1)
     np_, mp_ = idx_p.shape
+    nc = mp_ // mc
 
-    grid = (np_ // bn, mp_ // mc)
+    grid = (np_ // bn, nc)
     out = pl.pallas_call(
-        functools.partial(_oph_kernel, mc=mc, shift=shift, kp=kp),
+        functools.partial(_oph_kernel, mc=mc, shift=shift, kp=kp, nc=nc),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, c: (0, 0),
@@ -131,10 +136,11 @@ def oph_pallas(
             pl.BlockSpec((1, 1), lambda i, c: (0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((bn, mc), lambda i, c: (i, c)),
-            pl.BlockSpec((bn,), lambda i, c: (i,)),
+            pl.BlockSpec((bn, 1), lambda i, c: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bn, kp), lambda i, c: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, kp), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((bn, kp), jnp.int32)],
         interpret=interpret,
     )(a.reshape(1, 1), b.reshape(1, 1), idx_p, nnz_p)
     return out[:n, :k]

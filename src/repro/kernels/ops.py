@@ -53,9 +53,12 @@ from repro.perf import BBIT_KERNEL_MAX_V  # canonical home is perf; noqa
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is not None:
-        return interpret
-    return perf.choose("pallas_mode") != "compiled"
+    """An explicit ``interpret`` is a request, not an order: it goes
+    through the same eligibility filter, so a TPU always compiles and
+    other backends always interpret."""
+    impl = None if interpret is None else (
+        "interpret" if interpret else "compiled")
+    return perf.choose("pallas_mode", impl=impl) != "compiled"
 
 
 # ---------------------------------------------------------------------------

@@ -29,6 +29,16 @@ def minhash(indices: jax.Array, nnz: jax.Array, a: jax.Array,
     return jnp.min(h, axis=1)
 
 
+def pairwise_sum(x: jax.Array) -> jax.Array:
+    """Σ over axis 1 by repeated halving.  Every add has two operands,
+    so the bits do not depend on how XLA fuses or vectorizes the
+    reduction: the same rows give the same sum in any jitted program."""
+    while x.shape[1] > 1:
+        h = x.shape[1] // 2
+        x = jnp.concatenate([x[:, :h] + x[:, h:2 * h], x[:, 2 * h:]], axis=1)
+    return x[:, 0]
+
+
 def bbit_linear_fwd(codes: jax.Array, weights: jax.Array) -> jax.Array:
     """logits[n, c] = Σ_j W[j, codes[n, j], c].
 
@@ -40,7 +50,7 @@ def bbit_linear_fwd(codes: jax.Array, weights: jax.Array) -> jax.Array:
         codes.astype(jnp.int32)[:, :, None, None],
         axis=2,
     )[:, :, 0, :]
-    return gathered.astype(jnp.float32).sum(axis=1)
+    return pairwise_sum(gathered.astype(jnp.float32))
 
 
 def bbit_linear_bwd_dw(codes: jax.Array, dout: jax.Array,
@@ -72,7 +82,7 @@ def bbit_linear_packed_fwd(packed: jax.Array, weights: jax.Array,
     if empty is not None:
         mask = unpack_mask_jnp(empty, k)
         gathered = jnp.where(mask[:, :, None], 0.0, gathered)
-    return gathered.sum(axis=1)
+    return pairwise_sum(gathered)
 
 
 def bbit_linear_packed_bwd_dw(packed: jax.Array, dout: jax.Array,

@@ -8,19 +8,12 @@ jax import; tests and benches see the real single device).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:                                   # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                    # older jax: Auto is the only mode
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -49,10 +42,8 @@ def _make_1d_mesh(axis: str, n_devices=None) -> Mesh:
             f"{axis} mesh needs 1 <= n_devices <= {len(avail)} visible "
             f"devices, got {n} (set XLA_FLAGS="
             "--xla_force_host_platform_device_count=N for fake devices)")
-    devs = np.asarray(avail[:n])
-    if AxisType is not None:
-        return Mesh(devs, (axis,), axis_types=(AxisType.Auto,))
-    return Mesh(devs, (axis,))
+    return Mesh(np.asarray(avail[:n]), (axis,),
+                axis_types=(AxisType.Auto,))
 
 
 def make_data_mesh(n_devices=None) -> Mesh:

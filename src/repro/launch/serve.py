@@ -172,6 +172,8 @@ def main() -> None:
                          "config's profile_path if present) — drives "
                          "encode dispatch and micro-batch sizing")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.cache_entries is None:
         from repro.configs.rcv1_oph import CONFIG
         args.cache_entries = CONFIG.dedup_entries

@@ -151,7 +151,6 @@ def run_stream(args) -> dict:
             run_dir=os.path.join(args.workdir, "gang"),
             policy=CONFIG.restart_policy(),
             fault_spec=fault_spec,
-            local_devices=args.local_devices,
             ckpt_dir=os.path.join(args.workdir, "ckpt_stream"),
             seed=args.seed,
             **CONFIG.stream_kwargs(
@@ -282,10 +281,9 @@ def main() -> None:
     ap.add_argument("--procs", type=int, default=None,
                     help="stream mode: launch an N-process "
                          "jax.distributed gang (localhost) under "
-                         "gang-restart supervision")
-    ap.add_argument("--local-devices", type=int, default=1,
-                    help="stream mode with --procs: fake CPU devices "
-                         "per gang worker")
+                         "gang-restart supervision; CPU only (set "
+                         "XLA_FLAGS for fake devices per rank) — on "
+                         "chips use --data-parallel")
     ap.add_argument("--profile", default=None,
                     help="perf cost-model profile JSON (default: the "
                          "config's profile_path if it exists; missing/"
@@ -293,6 +291,8 @@ def main() -> None:
                          "dispatch heuristics)")
     args = ap.parse_args()
     os.makedirs(args.workdir, exist_ok=True)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro import perf
     from repro.configs.rcv1_oph import CONFIG
     profile = args.profile if args.profile is not None \

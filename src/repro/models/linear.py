@@ -49,6 +49,34 @@ def init_bbit_linear(cfg: BBitLinearConfig, key: Optional[jax.Array] = None):
     return {"table": table, "bias": bias}
 
 
+def _doubling_broadcast(v: jax.Array, n: int) -> jax.Array:
+    """(C,) → (n, C) by repeated self-concatenation.  Its transpose sums
+    the cotangent rows by halving — every add has two operands — so the
+    gradient does not depend on how XLA fuses or vectorizes a
+    reduction."""
+    x = v[None]
+    while x.shape[0] < n:
+        x = jnp.concatenate([x, x])
+    return x[:n]
+
+
+@jax.custom_jvp
+def _add_bias(out: jax.Array, bias: jax.Array) -> jax.Array:
+    """``out + bias`` whose bias gradient sums rows in a fixed order, so
+    a data-parallel step gives the same bits whether its shard slots run
+    on separate devices or fold onto one."""
+    return out + bias.astype(jnp.float32)
+
+
+@_add_bias.defjvp
+def _add_bias_jvp(primals, tangents):
+    out, bias = primals
+    t_out, t_bias = tangents
+    return (_add_bias(out, bias),
+            t_out + _doubling_broadcast(t_bias.astype(jnp.float32),
+                                        out.shape[0]))
+
+
 def _forced_impl(cfg: BBitLinearConfig, kernel: str, fallback: str
                  ) -> Optional[str]:
     """Map the config's ``use_kernel`` tri-state onto a perf pin:
@@ -94,14 +122,14 @@ def bbit_logits(params, codes: jax.Array, cfg: BBitLinearConfig,
             codes.astype(jnp.int32)[:, :, None, None],
             axis=2,
         )[:, :, 0, :].astype(jnp.float32)
-        out = jnp.where(empty[:, :, None], 0.0, gathered).sum(axis=1)
+        out = ref.pairwise_sum(jnp.where(empty[:, :, None], 0.0, gathered))
     elif logits_impl(cfg, rows=codes.shape[0]) == "kernel":
         out = ops.bbit_linear(codes.astype(jnp.int32), params["table"])
     else:
         out = ref.bbit_linear_fwd(codes, params["table"])
     if cfg.normalize:
         out = out / jnp.sqrt(jnp.float32(cfg.k))
-    return out + params["bias"].astype(jnp.float32)
+    return _add_bias(out, params["bias"])
 
 
 def bbit_logits_packed(params, packed: jax.Array, cfg: BBitLinearConfig,
@@ -124,7 +152,7 @@ def bbit_logits_packed(params, packed: jax.Array, cfg: BBitLinearConfig,
                                      cfg.b, empty=empty_packed)
         if cfg.normalize:
             out = out / jnp.sqrt(jnp.float32(cfg.k))
-        return out + params["bias"].astype(jnp.float32)
+        return _add_bias(out, params["bias"])
     from repro.core.bbit import unpack_codes_jnp, unpack_mask_jnp
     codes = unpack_codes_jnp(packed, cfg.k, cfg.b).astype(jnp.int32)
     empty = (unpack_mask_jnp(empty_packed, cfg.k)

@@ -31,12 +31,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                   # jax >= 0.5
-    from jax import shard_map
-except ImportError:                    # older jax keeps it in experimental
-    from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ArchConfig
 

@@ -14,10 +14,11 @@ point, :func:`choose`.  The selection order is
 with *eligibility* filtering applied before any of them: a forced or
 profiled impl that the hardware/shape cannot run (b outside the pack
 set, 2^b over the one-hot kernel ceiling, non-pow-2 OPH bins, compiled
-Pallas off-TPU) is ignored rather than crashed into.  Without a
-profile and without overrides every choice is bit-identical to the old
-scattered ``jax.default_backend() == "tpu"`` checks — this module is
-the only place in ``src/repro`` allowed to ask for the backend.
+Pallas off-TPU, interpreted Pallas on TPU) is ignored rather than
+crashed into.  Without a profile and without overrides every choice is
+bit-identical to the old scattered ``jax.default_backend() == "tpu"``
+checks — this module is the only place in ``src/repro`` allowed to ask
+for the backend.
 
 Profiles are versioned JSON keyed by a backend/device fingerprint
 (:func:`device_fingerprint`); a mismatched or corrupt profile is
@@ -141,10 +142,12 @@ def _logits_packed_eligible(shape) -> Tuple[str, ...]:
 
 
 def _pallas_mode_eligible(shape) -> Tuple[str, ...]:
-    # Mosaic lowering only exists on TPU; everywhere else Pallas runs
-    # in interpret mode
+    # Mosaic lowering only exists on TPU, and there it is the only
+    # mode: an interpreted kernel on a chip would hide the device
+    # behind a host loop.  Everywhere else Pallas runs in interpret
+    # mode.
     if jax.default_backend() == "tpu":
-        return ("compiled", "interpret")
+        return ("compiled",)
     return ("interpret",)
 
 

@@ -54,12 +54,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:                                   # moved out of experimental ≥ 0.5
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 
 from repro.distributed.collectives import psum_mean
 from repro.distributed.grad_compression import (
@@ -164,8 +160,13 @@ def build_dp_averaged_train_step(
 
             (_, (lsum, hits)), g = jax.value_and_grad(
                 local_objective, has_aux=True)(params)
-            return (lsum, hits.astype(jnp.float32),
-                    jnp.sum(valid_f.astype(jnp.float32)), g)
+            # the barrier keeps XLA from folding the running sum into
+            # this slot's gradient (e.g. as a scatter-add's initial
+            # value), which would round each slot differently from an
+            # unfolded device and break fold-invariance
+            return jax.lax.optimization_barrier(
+                (lsum, hits.astype(jnp.float32),
+                 jnp.sum(valid_f.astype(jnp.float32)), g))
 
         lsum, hits_f, rows, gsum = slot(params, 0)
         for f in range(1, fold):
@@ -248,16 +249,16 @@ def build_dp_averaged_train_step(
             _local, mesh=mesh,
             in_specs=(P(), P(), P(AXIS), P(AXIS), P(AXIS)),
             out_specs=(P(), P(), P()),
-            # the packed-logits custom_vjp has no replication rule;
+            # the packed-logits custom_vjp has no varying-axis rule;
             # outputs are replicated by construction (post-psum values
             # only)
-            check_rep=False)
+            check_vma=False)
     else:
         smapped = shard_map(
             _local_compressed, mesh=mesh,
             in_specs=((P(), P(AXIS)), P(), P(AXIS), P(AXIS), P(AXIS)),
             out_specs=((P(), P(AXIS)), P(), P()),
-            check_rep=False)
+            check_vma=False)
 
     def step(carry, active, batch, labels, valid):
         carry, loss, hits = smapped(carry, active, batch, labels,

@@ -212,6 +212,7 @@ def fit_streaming(
     prefetch: int = 2,
     data_parallel: Optional[int] = None,
     elastic: bool = False,
+    max_devices: Optional[int] = None,
     ckpt_dir: Optional[str] = None,
     ckpt_every_shards: int = 1,
     ckpt_keep_last: int = 3,
@@ -237,8 +238,10 @@ def fit_streaming(
     devices by adopting the checkpoint's logical schedule, and each
     physical realization is appended to the checkpoint's
     topology-lineage record (meta.json, ``StreamFitResult
-    .topology_lineage``).  ``watchdog`` (a ``ft.watchdog
-    .StepWatchdog``) observes per-step dispatch latency and escalates
+    .topology_lineage``).  ``max_devices`` caps the devices the slots
+    fold onto (default: every visible device).  ``watchdog`` (a
+    ``ft.watchdog.StepWatchdog``) observes per-step dispatch latency
+    and escalates
     persistent stragglers; ``ckpt_keep_last`` sizes the retained
     checkpoint ring (the fallback set when the newest checkpoint is
     torn/corrupt — see ``ckpt.checkpoint``'s durability contract).
@@ -492,6 +495,8 @@ def fit_streaming(
     d_local = 1
     if dp:
         n_dev = len(jax.devices())
+        if max_devices is not None:
+            n_dev = min(n_dev, int(max_devices))
         if procs > 1:
             # three-level fold: logical slots → per-process contiguous
             # blocks → per-device fold within each process
@@ -505,7 +510,8 @@ def fit_streaming(
                     f"but only {n_dev} are visible — pass elastic=True "
                     "to fold the logical shard slots onto the "
                     "available devices")
-            physical = physical_data_world(logical) if elastic else logical
+            physical = (physical_data_world(logical, n_dev) if elastic
+                        else logical)
             mesh = mesh_from_available_devices(model_parallel=1,
                                                max_devices=physical)
         if procs > 1:

@@ -223,7 +223,6 @@ def run_multiprocess_supervised(
     run_dir: str,
     policy: Optional[RestartPolicy] = None,
     fault_spec: Optional[dict] = None,
-    local_devices: int = 1,
     attempt_timeout_s: float = 600.0,
     **fit_kwargs,
 ) -> MultiProcessRun:
@@ -233,8 +232,12 @@ def run_multiprocess_supervised(
 
     Each attempt binds a fresh coordinator port, writes one JSON spec
     per rank under ``run_dir`` and execs ``python -m
-    repro.train.worker`` per rank (``local_devices`` fake CPU devices
-    each, via ``XLA_FLAGS``).  The first non-zero worker exit kills
+    repro.train.worker`` per rank; workers inherit this process's
+    environment, so its ``JAX_PLATFORMS`` and ``XLA_FLAGS`` (e.g. fake
+    CPU devices per rank) set what each rank sees.  All ranks run on
+    this host, so a backend with chips is refused: this process already
+    holds them, and one process drives every local chip through
+    ``data_parallel`` instead.  The first non-zero worker exit kills
     the WHOLE gang (a dead rank cannot rejoin live collectives) and —
     within ``policy.max_restarts`` — respawns it; every worker resumes
     from the latest valid coordinated checkpoint, so the finished
@@ -245,6 +248,14 @@ def run_multiprocess_supervised(
     """
     if procs < 1:
         raise ValueError(f"procs must be >= 1, got {procs}")
+    from repro import perf
+    backend = perf.device_fingerprint()["backend"]
+    if procs > 1 and backend != "cpu":
+        raise ValueError(
+            f"a {procs}-process gang on one {backend} host cannot share "
+            f"its chips, and this process already holds them: run one "
+            f"process with data_parallel={procs} (--data-parallel "
+            f"{procs}) instead")
     if not fit_kwargs.get("ckpt_dir"):
         raise ValueError(
             "run_multiprocess_supervised requires ckpt_dir: a gang "
@@ -261,9 +272,6 @@ def run_multiprocess_supervised(
     cfg_dict = _dc.asdict(cfg)
 
     env_base = dict(os.environ)
-    env_base["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={int(local_devices)}")
-    env_base["JAX_PLATFORMS"] = "cpu"
     env_base["PYTHONPATH"] = (
         _src_root() + os.pathsep + env_base.get("PYTHONPATH", ""))
 

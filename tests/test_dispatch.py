@@ -94,6 +94,21 @@ def test_eligibility_filters_before_any_override():
     assert perf.dispatch_report()["ineligible_overrides"] == 1
 
 
+@pytest.mark.parametrize("backend,mode", [("tpu", "compiled"),
+                                          ("cpu", "interpret")])
+def test_pallas_mode_has_one_eligible_arm(monkeypatch, backend, mode):
+    """A TPU only compiles Pallas and other backends only interpret:
+    no env pin, profile or explicit request can pick the other mode."""
+    from repro.kernels.ops import _auto_interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert OPS["pallas_mode"].eligible({}) == (mode,)
+    other = "interpret" if mode == "compiled" else "compiled"
+    monkeypatch.setenv(perf.ENV_DISPATCH, f"pallas_mode={other}")
+    assert perf.choose("pallas_mode") == mode
+    for request in (None, True, False):
+        assert _auto_interpret(request) == (mode == "interpret")
+
+
 # ---------------------------------------------------------------------------
 # forced implementations agree
 

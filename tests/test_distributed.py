@@ -8,15 +8,13 @@ from conftest import run_in_subprocess
 def test_grad_compression_and_hlo_accounting():
     run_in_subprocess("""
         import functools, numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:        # jax<0.5 keeps it in experimental
-            from jax.experimental.shard_map import shard_map
+        from jax.sharding import AxisType, PartitionSpec as P
+        from jax import shard_map
         from repro.distributed import (
             compressed_allreduce_mean, collective_bytes_from_hlo,
             collective_stats_from_hlo)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         g = jnp.arange(4*64, dtype=jnp.float32).reshape(4, 64) / 100.
         e = jnp.zeros((4, 64), jnp.float32)
         @functools.partial(shard_map, mesh=mesh,
@@ -60,14 +58,12 @@ def test_grad_compression_and_hlo_accounting():
 def test_sequence_parallel_primitives():
     run_in_subprocess("""
         import functools, numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:        # jax<0.5 keeps it in experimental
-            from jax.experimental.shard_map import shard_map
+        from jax.sharding import AxisType, PartitionSpec as P
+        from jax import shard_map
         from repro.distributed import (merge_partial_attention,
                                        seq_parallel_ssm_scan)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         scores = np.random.default_rng(2).normal(size=(2, 32)).astype('f')
         V = np.random.default_rng(3).normal(size=(32, 5)).astype('f')
         full = jax.nn.softmax(jnp.asarray(scores), -1) @ jnp.asarray(V)
@@ -104,13 +100,11 @@ def test_sequence_parallel_primitives():
 def test_pipeline_parallel_gpipe():
     run_in_subprocess("""
         import functools, numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:        # jax<0.5 keeps it in experimental
-            from jax.experimental.shard_map import shard_map
+        from jax.sharding import AxisType, PartitionSpec as P
+        from jax import shard_map
         from repro.distributed import pipelined_apply
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         M, mb, dim = 6, 2, 8
         x = np.random.default_rng(6).normal(size=(M, mb, dim)).astype('f')
         W = np.random.default_rng(7).normal(size=(4, dim, dim)
@@ -218,10 +212,11 @@ def test_moe_weight_stationary_serving_parity():
     the dense fallback exactly (ample capacity)."""
     run_in_subprocess("""
         import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs.base import ArchConfig
         import repro.models.moe as M
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         M.EXPERT_PAD_TO = 8
         cfg = ArchConfig(name="m", family="moe", n_layers=1, d_model=16,
                          n_heads=2, n_kv_heads=2, d_ff=0, vocab=64,

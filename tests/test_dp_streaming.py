@@ -41,10 +41,7 @@ def test_psum_mean_preserves_dtype_under_shard_map():
     run_in_subprocess("""
         import functools, numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.distributed import psum_mean
         from repro.launch.mesh import make_data_mesh
         mesh = make_data_mesh(2)
@@ -192,6 +189,31 @@ def test_dp_streaming_single_device_world_matches_semantics():
     """, devices=2)
 
 
+def test_dp_streaming_max_devices_folds_onto_fewer():
+    """``max_devices=1`` folds a logical dp=2 schedule onto one of two
+    visible devices: same steps, same parameters bit for bit, and the
+    lineage records the physical world actually used."""
+    run_in_subprocess(_DP_COMMON + """
+    with tempfile.TemporaryDirectory() as d:
+        make_archive(d, n_docs=200, n_shards=4)
+        lcfg = BBitLinearConfig(k=16, b=4)
+        kw = dict(epochs=1, batch_size=32, lr=5e-3, seed=0,
+                  data_parallel=2, elastic=True)
+        runs = {cap: fit_streaming(d, lcfg, max_devices=cap,
+                                   ckpt_dir=f"{d}/ck{cap}", **kw)
+                for cap in (None, 1)}
+        two, one = runs[None], runs[1]
+        assert [e["physical"] for e in two.topology_lineage] == [2]
+        assert [e["physical"] for e in one.topology_lineage] == [1]
+        assert (one.n_steps, one.examples_seen) == (two.n_steps,
+                                                    two.examples_seen)
+        for x, y in zip(jax.tree.leaves(one.params),
+                        jax.tree.leaves(two.params)):
+            assert np.array_equal(np.asarray(x), np.asarray(y))
+        print("OK")
+    """, devices=2)
+
+
 # ------------------------------------------------ in-process (CI tier) ----
 needs_two = pytest.mark.skipif(
     len(jax.devices()) < 2,
@@ -223,10 +245,7 @@ def test_psum_mean_dtype_in_process():
     import functools
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.distributed import psum_mean
     from repro.launch.mesh import make_data_mesh
 

@@ -13,6 +13,7 @@ invariant, so a 2-process×1-device gang, a 1-process×1-device fold-2
 run, and any kill/resume splice of the two must produce IDENTICAL
 parameters.
 """
+import contextlib
 import json
 import os
 
@@ -87,15 +88,34 @@ def baseline(archive):
             "progressive_acc": res.progressive_acc}
 
 
+@contextlib.contextmanager
+def cpu_worker_env(local_devices):
+    """Gang workers inherit this process's environment: run them on
+    the CPU backend with ``local_devices`` fake devices each."""
+    pins = {"JAX_PLATFORMS": "cpu",
+            "XLA_FLAGS": "--xla_force_host_platform_device_count="
+                         f"{int(local_devices)}"}
+    saved = {key: os.environ.get(key) for key in pins}
+    os.environ.update(pins)
+    try:
+        yield
+    finally:
+        for key, val in saved.items():
+            if val is None:
+                os.environ.pop(key, None)
+            else:
+                os.environ[key] = val
+
+
 def _gang(archive, run_dir, *, procs=2, local_devices=1, fault=None,
           **overrides):
     kw = dict(FIT)
     kw.update(overrides)
-    return run_multiprocess_supervised(
-        archive, CFG, procs=procs, run_dir=run_dir,
-        local_devices=local_devices, policy=POLICY,
-        fault_spec=fault.to_spec() if fault else None,
-        ckpt_dir=os.path.join(run_dir, "ckpt"), **kw)
+    with cpu_worker_env(local_devices):
+        return run_multiprocess_supervised(
+            archive, CFG, procs=procs, run_dir=run_dir, policy=POLICY,
+            fault_spec=fault.to_spec() if fault else None,
+            ckpt_dir=os.path.join(run_dir, "ckpt"), **kw)
 
 
 # ------------------------------------------------------- unit layer ----
@@ -373,3 +393,16 @@ def test_multiprocess_requires_dp_and_ckpt(archive, tmp_path):
     with pytest.raises(ValueError, match="data_parallel"):
         fit_streaming(archive, CFG, runtime=rt, epochs=1,
                       batch_size=BATCH)
+
+
+def test_multiprocess_refused_on_chip_backend(archive, tmp_path,
+                                               monkeypatch):
+    """A one-host gang cannot share chips this process already holds:
+    on any non-CPU backend the launcher points at data_parallel."""
+    from repro import perf
+    monkeypatch.setattr(perf, "device_fingerprint",
+                        lambda: {"backend": "tpu"})
+    with pytest.raises(ValueError, match="--data-parallel 2"):
+        run_multiprocess_supervised(
+            archive, CFG, procs=2, run_dir=str(tmp_path),
+            ckpt_dir=str(tmp_path / "ckpt"), **FIT)
