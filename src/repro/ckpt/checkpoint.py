@@ -60,6 +60,7 @@ import jax
 import numpy as np
 
 from repro.ft import faults
+from repro.perf.spans import span
 
 CKPT_FORMAT = 4
 QUARANTINE_SUBDIR = "quarantine"
@@ -139,16 +140,37 @@ def save(root: str, step: int, tree: Any, keep_last: int = 3, *,
     stores its schedule + topology lineage there.
     """
     os.makedirs(root, exist_ok=True)
+    arrays = read_leaves(tree)
+    with span("repro.ckpt.write", step=step):
+        return _write(root, step, arrays, keep_last, extra_meta)
+
+
+def device_leaves(tree: Any) -> int:
+    """How many leaves of ``tree`` live on a device (each one a
+    device → host read when the tree is saved)."""
+    return sum(isinstance(x, jax.Array) for x in jax.tree.leaves(tree))
+
+
+def read_leaves(tree: Any) -> dict:
+    """The leaves of ``tree`` as host arrays named ``leaf_<i>``: the
+    device → host reads of a save, under ``repro.ckpt.read_device``."""
     leaves = jax.tree.leaves(tree)
-    arrays = {f"leaf_{i:05d}": np.asarray(jax.device_get(x))
-              for i, x in enumerate(leaves)}
+    with span("repro.ckpt.read_device", n=device_leaves(tree)):
+        return {f"leaf_{i:05d}": np.asarray(jax.device_get(x))
+                for i, x in enumerate(leaves)}
+
+
+def _write(root: str, step: int, arrays: dict, keep_last: int,
+           extra_meta: Optional[dict]) -> str:
+    """Serialise, checksum, fsync, rename and prune: ``save`` after
+    its leaves are on the host."""
     directive = faults.on_ckpt_write(step) if faults._ACTIVE is not None \
         else None
     tmp = os.path.join(root, f".tmp-{step}")
     os.makedirs(tmp, exist_ok=True)
     payload = os.path.join(tmp, "ckpt.npz")
     np.savez(payload, **arrays)
-    meta = {"step": int(step), "n_leaves": len(leaves),
+    meta = {"step": int(step), "n_leaves": len(arrays),
             "ckpt_format": CKPT_FORMAT,
             "crc32": {k: zlib.crc32(np.ascontiguousarray(v).tobytes())
                       for k, v in arrays.items()}}

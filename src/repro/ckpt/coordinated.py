@@ -62,10 +62,10 @@ import time
 import zlib
 from typing import Any, Optional
 
-import jax
 import numpy as np
 
 from repro.ft import faults
+from repro.perf.spans import span
 
 log = logging.getLogger("repro.ckpt")
 
@@ -90,8 +90,10 @@ def is_coordinated_dir(step_dir: str) -> bool:
 
 
 def _write_rank_payload(stage: str, rank: int, step: int,
-                        tree: Any) -> None:
-    """Stage one rank's CRC'd payload atomically (tmp + fsync + rename).
+                        arrays: dict) -> None:
+    """Stage one rank's CRC'd payload (its leaves on the host, as
+    ``checkpoint.read_leaves`` returns them) atomically (tmp + fsync +
+    rename).
 
     Honors the armed ``ckpt_write`` fault exactly like ``checkpoint
     .save``: a ``"torn"`` directive truncates the payload AFTER the
@@ -100,9 +102,6 @@ def _write_rank_payload(stage: str, rank: int, step: int,
     """
     from repro.ckpt.checkpoint import _fsync_path
 
-    leaves = jax.tree.leaves(tree)
-    arrays = {f"leaf_{i:05d}": np.asarray(jax.device_get(x))
-              for i, x in enumerate(leaves)}
     directive = faults.on_ckpt_write(step) if faults._ACTIVE is not None \
         else None
     tmp = os.path.join(stage, f".tmp-{_rank_name(rank)}")
@@ -112,7 +111,7 @@ def _write_rank_payload(stage: str, rank: int, step: int,
     payload = os.path.join(tmp, "ckpt.npz")
     np.savez(payload, **arrays)
     meta = {"rank": int(rank), "step": int(step),
-            "n_leaves": len(leaves),
+            "n_leaves": len(arrays),
             "ckpt_format": COORDINATED_FORMAT,
             "crc32": {k: zlib.crc32(np.ascontiguousarray(v).tobytes())
                       for k, v in arrays.items()}}
@@ -162,6 +161,7 @@ def save_coordinated(
     is staged)."""
     from repro.ckpt.checkpoint import (
         _fsync_path, _read_manifest, _step_dir, _write_manifest,
+        read_leaves,
     )
 
     os.makedirs(root, exist_ok=True)
@@ -174,7 +174,9 @@ def save_coordinated(
     # payload is byte-identical to the one this attempt re-stages
     # (atomically, rename-replace) over it.
     os.makedirs(stage, exist_ok=True)
-    _write_rank_payload(stage, rank, step, tree)
+    arrays = read_leaves(tree)
+    with span("repro.ckpt.write", step=step):
+        _write_rank_payload(stage, rank, step, arrays)
     if rank != 0:
         return None
 

@@ -60,10 +60,12 @@ from typing import Any, Callable, Iterator, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.data.hashed_dataset import iter_hashed_batches
+from repro.perf.spans import span
 
 __all__ = [
     "StreamBatch", "Boundary", "ShardStreamError", "shard_order",
     "serial_batch_stream", "group_batch_stream", "ThreadedPrefetcher",
+    "InlineStream",
 ]
 
 
@@ -157,12 +159,19 @@ def serial_batch_stream(
         for pos in range(first, n_shards):
             s = int(order[pos])
             try:
-                for bp, bl, _rid, bem in iter_hashed_batches(
-                        root, batch_size, shard_ids=[s],
-                        perm_seed=(seed, epoch), mmap=mmap):
+                batches = iter_hashed_batches(
+                    root, batch_size, shard_ids=[s],
+                    perm_seed=(seed, epoch), mmap=mmap)
+                while True:
+                    with span("repro.prefetch.read", epoch=epoch, shard=s):
+                        item = next(batches, None)
+                    if item is None:
+                        break
+                    bp, bl, _rid, bem = item
                     _mask_consistent(bem, has_empty, s, root)
-                    yield StreamBatch(args=transfer(bp, bem, bl),
-                                      n_rows=len(bl))
+                    with span("repro.prefetch.put", n=len(bl)):
+                        args = transfer(bp, bem, bl)
+                    yield StreamBatch(args=args, n_rows=len(bl))
             except Exception as e:
                 # GeneratorExit (consumer close) is BaseException —
                 # deliberately not caught here
@@ -246,43 +255,46 @@ def group_batch_stream(
                 for d in range(len(group)) if slot_lo <= d < slot_hi}
             n_batches = [-(-counts[s] // batch_size) for s in group]
             for t in range(max(n_batches)):
-                codes = np.zeros((local, batch_size, packed_width),
-                                 np.uint8)
-                empty = (np.zeros((local, batch_size, mask_width),
-                                  np.uint8) if has_empty else None)
-                labels = np.zeros((local, batch_size), np.int32)
-                valid = np.zeros((local, batch_size), bool)
-                for d, it in iters.items():
-                    if t >= n_batches[d]:
-                        continue
-                    try:
-                        bp, bl, _rid, bem = next(it)
-                        _mask_consistent(bem, has_empty, group[d], root)
-                    except StopIteration as e:
-                        raise RuntimeError(
-                            f"shard {group[d]} yielded fewer batches "
-                            f"than its row count promised") from e
-                    except Exception as e:
-                        raise ShardStreamError(
-                            f"shard {group[d]} (device slot {d}) failed "
-                            f"at epoch {epoch} position {lo + d} of "
-                            f"{root!r}: {e}", shard=group[d],
-                            epoch=epoch, position=lo + d) from e
-                    m = len(bl)
-                    codes[d - slot_lo, :m] = bp
-                    labels[d - slot_lo, :m] = bl
-                    valid[d - slot_lo, :m] = True
-                    if has_empty:
-                        empty[d - slot_lo, :m] = bem
+                with span("repro.prefetch.read", epoch=epoch,
+                          shard=group[0]):
+                    codes = np.zeros((local, batch_size, packed_width),
+                                     np.uint8)
+                    empty = (np.zeros((local, batch_size, mask_width),
+                                      np.uint8) if has_empty else None)
+                    labels = np.zeros((local, batch_size), np.int32)
+                    valid = np.zeros((local, batch_size), bool)
+                    for d, it in iters.items():
+                        if t >= n_batches[d]:
+                            continue
+                        try:
+                            bp, bl, _rid, bem = next(it)
+                            _mask_consistent(bem, has_empty, group[d],
+                                             root)
+                        except StopIteration as e:
+                            raise RuntimeError(
+                                f"shard {group[d]} yielded fewer batches "
+                                f"than its row count promised") from e
+                        except Exception as e:
+                            raise ShardStreamError(
+                                f"shard {group[d]} (device slot {d}) "
+                                f"failed at epoch {epoch} position "
+                                f"{lo + d} of {root!r}: {e}", shard=group[d],
+                                epoch=epoch, position=lo + d) from e
+                        m = len(bl)
+                        codes[d - slot_lo, :m] = bp
+                        labels[d - slot_lo, :m] = bl
+                        valid[d - slot_lo, :m] = True
+                        if has_empty:
+                            empty[d - slot_lo, :m] = bem
                 # the GLOBAL real-row count for this step — a pure
                 # function of the row counts, so every process agrees
                 # without reading each other's shards
                 n_rows = sum(
                     min(batch_size, counts[group[d]] - t * batch_size)
                     for d in range(len(group)) if t < n_batches[d])
-                yield StreamBatch(
-                    args=transfer(codes, empty, labels, valid),
-                    n_rows=n_rows)
+                with span("repro.prefetch.put", n=n_rows):
+                    args = transfer(codes, empty, labels, valid)
+                yield StreamBatch(args=args, n_rows=n_rows)
             next_epoch, next_pos = ((epoch, lo + world)
                                     if lo + world < n_shards
                                     else (epoch + 1, 0))
@@ -303,6 +315,11 @@ class ThreadedPrefetcher:
     queue and joins the thread.  Exhausting the stream normally needs
     no cleanup but ``close()`` is idempotent and cheap.
     """
+
+    # times the consumer found the queue empty (the trainer's
+    # ``input_waits`` counter); on the class, so an instance built
+    # without ``__init__`` counts too
+    waits = 0
 
     def __init__(self, gen: Iterator[Any], depth: int = 2):
         if depth < 1:
@@ -336,13 +353,10 @@ class ThreadedPrefetcher:
     def __iter__(self):
         return self
 
-    def __next__(self):
-        if self._done:
-            raise StopIteration
+    def _get(self):
         while True:
             try:
-                kind, val = self._q.get(timeout=0.25)
-                break
+                return self._q.get(timeout=0.25)
             except queue.Empty:
                 # the sentinel protocol means a live producer ALWAYS
                 # eventually posts; a dead thread with an empty queue
@@ -355,6 +369,14 @@ class ThreadedPrefetcher:
                         "prefetch producer thread died without "
                         "delivering an event or error sentinel — "
                         "the stream is lost") from None
+
+    def __next__(self):
+        if self._done:
+            raise StopIteration
+        if self._q.empty():
+            self.waits += 1
+        with span("repro.prefetch.wait"):
+            kind, val = self._get()
         if kind == "item":
             return val
         self._done = True
@@ -388,3 +410,29 @@ class ThreadedPrefetcher:
     def __exit__(self, *exc):
         self.close()
         return False
+
+
+class InlineStream:
+    """An event generator run on the consumer's own thread (prefetch
+    off), under the same ``repro.prefetch.wait`` span as
+    ``ThreadedPrefetcher``: the producer's read and put spans nest
+    inside it.  Every event is a wait (``waits``), since the consumer
+    produces each one itself."""
+
+    def __init__(self, gen: Iterator[Any]):
+        self._gen = gen
+        self.waits = 0
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        with span("repro.prefetch.wait"):
+            item = next(self._gen)
+        self.waits += 1
+        return item
+
+    def close(self) -> None:
+        # runs the generator's cleanup now (its open mmap'd shard
+        # iterators) instead of waiting on GC
+        self._gen.close()

@@ -13,7 +13,8 @@ Three entry modes:
     newest valid checkpoint (torn/corrupt ones are quarantined) after a
     capped backoff, ``elastic`` folds the logical data-parallel world
     onto whatever devices are alive, and ``--fail-at`` injects a
-    deterministic crash to watch it self-heal.
+    deterministic crash to watch it self-heal.  Its result line ends
+    with the loop's counters (docs/DESIGN.md §2.7).
   * ``--mode lm``: trains a (reduced) LM-zoo arch on synthetic tokens
     through the same TrainState/checkpoint machinery (smoke-scale on
     CPU; the full configs are exercised by the dry-run).
@@ -158,15 +159,18 @@ def run_stream(args) -> dict:
                 lr=args.lr, ckpt_every_shards=1,
                 data_parallel=args.data_parallel or args.procs))
         rec = run.result
+        by_rank = [run.results[r]["counters"] for r in sorted(run.results)]
         print(f"gang of {args.procs} procs streamed "
               f"{rec['examples_seen']} rows x {args.epochs} epochs in "
               f"{rec['train_seconds']:.1f}s: progressive_acc="
               f"{rec['progressive_acc']:.4f} steps={rec['n_steps']} "
               f"gang_restarts={run.restarts} "
-              f"topology={rec['lineage']}")
+              f"topology={rec['lineage']} "
+              f"counters_by_rank={by_rank}")
         return dict(progressive_acc=rec["progressive_acc"],
                     steps=rec["n_steps"], restarts=run.restarts,
-                    crashes=[c.error for c in run.crashes])
+                    crashes=[c.error for c in run.crashes],
+                    counters_by_rank=by_rank)
 
     if args.fail_at is not None:
         faults.arm_plan(FaultPlan([
@@ -193,10 +197,12 @@ def run_stream(args) -> dict:
           f"stragglers={sup.straggler_escalations} "
           f"topology={res.topology_lineage} "
           f"dispatch={res.dispatch} "
-          f"(profile_hits={rep['hits']} fallbacks={rep['fallbacks']})")
+          f"(profile_hits={rep['hits']} fallbacks={rep['fallbacks']}) "
+          f"counters={res.counters}")
     return dict(progressive_acc=res.progressive_acc,
                 steps=res.n_steps, restarts=sup.restarts,
-                crashes=[c.error for c in sup.crashes])
+                crashes=[c.error for c in sup.crashes],
+                counters=res.counters)
 
 
 def run_lm(args) -> dict:

@@ -87,7 +87,8 @@ def run_spec(spec: dict) -> int:
                "progressive_acc": result.progressive_acc,
                "completed": result.completed,
                "train_seconds": result.train_seconds,
-               "lineage": result.topology_lineage}
+               "lineage": result.topology_lineage,
+               "counters": result.counters}
         with open(spec["result_path"], "w") as f:
             json.dump(rec, f)
     heartbeat(rt, step=result.n_steps,
