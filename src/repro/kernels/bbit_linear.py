@@ -31,7 +31,11 @@ Layout: rows sit on the 128 lanes and hash functions on sublanes.  The
 wrappers hand the kernels the codes (or packed bytes) transposed,
 ``(k, n)``, and the table as ``(k, C, V)``, so one hash function's codes
 are a (1, BN) row read at a dynamic sublane offset inside a
-``fori_loop``, and its one-hot is a (V, BN) sublane-iota compare.  Every
+``fori_loop``, and its one-hot is a (V, BN) sublane-iota compare.  Both
+directions contract that one tile as it stands: the forward against the
+(C, V) weight slab over its sublanes, the backward against dout, handed
+in lane-major as ``(C, n)``, over its lanes, writing (C, V) straight
+into the ``(k, C, V)`` dW.  Neither loop body transposes.  Every
 block then meets the TPU rule that its last two dimensions are whole
 (8, 128) tiles or the whole array.  The contraction order is that of a
 loop over hash functions, so the packed and widened kernels agree bit
@@ -100,25 +104,24 @@ def _fwd_body(row_fn, w_ref, out_ref, bj: int):
 
 
 def _bwd_body(row_fn, dout_ref, dw_ref, bj: int):
-    """dW[jj]ᵀ (C, V) += (onehot(jj)ᵀ (V, BN) · dout (BN, C))ᵀ.  The one-hot
-    is built column-wise from the transposed code row so that the
-    contraction over rows has the same form as ``ref.bbit_linear_bwd_dw``."""
+    """dW[jj]ᵀ (C, V) += dout (C, BN) · onehot(jj)ᵀ (BN, V).  The one-hot
+    is the forward's (V, BN) tile and dout arrives lane-major, so the
+    contraction runs over the lanes of both operands and its (C, V)
+    result lands in the output block as is: no relayout in the loop."""
     i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
-    dout = dout_ref[...]                                # (BN, C)
+    dout = dout_ref[...]                                # (C, BN)
     v = dw_ref.shape[2]
 
     def step(jj, carry):
-        col = row_fn(jj).T                              # (BN, 1)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (col.shape[0], v), 1)
-        onehot = (col == iota).astype(dout.dtype)       # (BN, V)
+        onehot = _onehot_t(row_fn(jj), v, dout.dtype)   # (V, BN)
         dw_ref[jj] = dw_ref[jj] + jax.lax.dot_general(
-            onehot, dout, (((0,), (0,)), ((), ())),
-            precision=_HIGHEST, preferred_element_type=jnp.float32).T
+            dout, onehot, (((1,), (1,)), ((), ())),
+            precision=_HIGHEST, preferred_element_type=jnp.float32)
         return carry
 
     jax.lax.fori_loop(0, bj, step, 0)
@@ -185,23 +188,23 @@ def _call_fwd(kernel, row_inputs, row_specs, weights, bn, bj, kp,
     return out.T
 
 
-def _call_bwd(kernel, row_inputs, row_specs, dout, n, vsize, bn, bj, kp,
+def _call_bwd(kernel, row_inputs, row_specs, dout, vsize, bn, bj, kp,
               scratch, interpret):
     """Grid (kp/BJ, n/BN): accumulate over example blocks (dim 1).
     Padded examples carry zero dout → no effect."""
     c = dout.shape[1]
     np_ = row_inputs[0].shape[1]
-    dout_p = jnp.pad(dout.astype(jnp.float32), ((0, np_ - n), (0, 0)))
+    dout_t = _pad_rows_t(dout.astype(jnp.float32), np_)
     dw_t = pl.pallas_call(
         kernel,
         grid=(kp // bj, np_ // bn),
         in_specs=row_specs(lambda j, i: (j, i))
-        + [pl.BlockSpec((bn, c), lambda j, i: (i, 0))],
+        + [pl.BlockSpec((c, bn), lambda j, i: (0, i))],
         out_specs=pl.BlockSpec((bj, c, vsize), lambda j, i: (j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((kp, c, vsize), jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(*row_inputs, dout_p)
+    )(*row_inputs, dout_t)
     return dw_t.transpose(0, 2, 1)
 
 
@@ -258,7 +261,7 @@ def bbit_linear_bwd_dw_pallas(
     bn, bj, kp = _blocks(n, k, block_n, block_j)
     inputs, specs = _widened_inputs(codes, bn, bj, kp)
     dw = _call_bwd(_widened_kernel(_bwd_body, bj), inputs, specs, dout,
-                   n, vsize, bn, bj, kp, [], interpret)
+                   vsize, bn, bj, kp, [], interpret)
     return dw[:k]
 
 
@@ -363,6 +366,6 @@ def bbit_linear_packed_bwd_dw_pallas(
     inputs, specs, scratch = _packed_inputs(packed, empty, k, bits,
                                             bn, bj, kp)
     kernel = _packed_kernel(_bwd_body, bj, bits, empty is not None)
-    dw = _call_bwd(kernel, inputs, specs, dout, n, vsize, bn, bj, kp,
+    dw = _call_bwd(kernel, inputs, specs, dout, vsize, bn, bj, kp,
                    scratch, interpret)
     return dw[:k]

@@ -80,6 +80,76 @@ def test_packed_kernel_masked_matches_reference(b, k, empty_frac):
 
 
 @pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("b", [1, 8])
+@pytest.mark.parametrize("c", [1, 3])
+def test_packed_dw_accumulates_across_row_blocks(c, b, masked):
+    """n = 300 is three 128-row blocks, the last ragged, so dW sums over
+    the row grid; C = 1 is the lane-major dout the streaming trainer
+    runs.  Packed == widened bit for bit (a marked empty bin is the
+    widened code 2^b), and both match the reference."""
+    k, n = 37, 300
+    codes, packed, _, empty, dout = _case(b, k, n=n, c=c, seed=7 * b + c,
+                                          empty_frac=0.4 if masked else 0.0)
+    v = 1 << b
+    wide = codes.astype(np.int32)
+    if masked:
+        wide[np.unpackbits(np.asarray(empty), axis=1)[:, :k] != 0] = v
+    dwide = bbit_linear_bwd_dw_pallas(jnp.asarray(wide), dout, v,
+                                      interpret=True)
+    dgot = bbit_linear_packed_bwd_dw_pallas(packed, dout, v, k=k, bits=b,
+                                            empty=empty, interpret=True)
+    assert np.array_equal(np.asarray(dwide), np.asarray(dgot))
+    dwant = ref.bbit_linear_packed_bwd_dw(packed, dout, v, k, b,
+                                          empty=empty)
+    np.testing.assert_allclose(np.asarray(dwant), np.asarray(dgot),
+                               rtol=1e-5, atol=1e-5)
+
+
+def _kernel_eqns(jaxpr, inside=False):
+    """Every eqn of the Pallas kernel bodies in ``jaxpr``, loop and
+    branch bodies included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield from _kernel_eqns(eqn.params["jaxpr"], True)
+            continue
+        if inside:
+            yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernel_eqns(sub, inside)
+
+
+@pytest.mark.parametrize("kernel", ["packed", "packed_masked", "widened"])
+def test_dw_kernel_contracts_lanes_without_transpose(kernel):
+    """The dW loop contracts the forward's (V, BN) one-hot against
+    lane-major dout (C, BN) over the lanes of both: no transpose inside
+    the kernel body, one (((1,), (1,)), ((), ())) dot_general."""
+    k, b, n = 256, 8, 300
+    v = 1 << b
+    dout = jax.ShapeDtypeStruct((n, 1), jnp.float32)
+    if kernel == "widened":
+        jaxpr = jax.make_jaxpr(
+            lambda x, d: bbit_linear_bwd_dw_pallas(x, d, v))(
+            jax.ShapeDtypeStruct((n, k), jnp.int32), dout)
+    else:
+        empty = (jax.ShapeDtypeStruct((n, k // 8), jnp.uint8)
+                 if kernel == "packed_masked" else None)
+        jaxpr = jax.make_jaxpr(
+            lambda x, d, e: bbit_linear_packed_bwd_dw_pallas(
+                x, d, v, k=k, bits=b, empty=e))(
+            jax.ShapeDtypeStruct((n, k * b // 8), jnp.uint8), dout, empty)
+    eqns = list(_kernel_eqns(jaxpr.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert names, "no Pallas kernel body found"
+    assert "transpose" not in names
+    dots = [e.params["dimension_numbers"] for e in eqns
+            if e.primitive.name == "dot_general"]
+    assert dots == [(((1,), (1,)), ((), ()))]
+
+
+@pytest.mark.parametrize("masked", [False, True])
 def test_packed_custom_vjp_grads_match_reference(masked):
     k, b = 16, 4
     _, packed, weights, empty, _ = _case(b, k,

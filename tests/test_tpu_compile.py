@@ -80,20 +80,22 @@ def test_fused_encode_compiles(one_chip, scheme, bits):
     assert compiled.memory_analysis() is not None
 
 
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_packed_logits_compile(one_chip, direction):
+@pytest.mark.parametrize("direction,c", [
+    pytest.param("fwd", 1, id="fwd"), pytest.param("bwd", 1, id="bwd"),
+    pytest.param("bwd", 3, id="bwd-c3")])
+def test_packed_logits_compile(one_chip, direction, c):
     bits, v = 8, 256
     packed = jax.ShapeDtypeStruct((BATCH, K * bits // 8), jnp.uint8,
                                   sharding=one_chip)
     if direction == "fwd":
         fn = lambda p, w: bbit_linear.bbit_linear_packed_fwd_pallas(  # noqa
             p, w, k=K, bits=bits)
-        other = jax.ShapeDtypeStruct((K, v, 1), jnp.float32,
+        other = jax.ShapeDtypeStruct((K, v, c), jnp.float32,
                                      sharding=one_chip)
     else:
         fn = lambda p, d: bbit_linear.bbit_linear_packed_bwd_dw_pallas(  # noqa
             p, d, v, k=K, bits=bits)
-        other = jax.ShapeDtypeStruct((BATCH, 1), jnp.float32,
+        other = jax.ShapeDtypeStruct((BATCH, c), jnp.float32,
                                      sharding=one_chip)
     _, text = _compile(fn, packed, other)
     assert CUSTOM_CALL in text
