@@ -6,7 +6,10 @@ Production settings follow the paper's best-performing regime
 trained with LR (Eq. 9) or L2-SVM (Eq. 8) at LIBLINEAR C∈[1e-3,1e2].
 The multi-pod dry-run lowers this model's train_step on the production
 mesh with the (k, 2^b, C) weight table sharded over 'model' (TP over k)
-and the batch over ('pod','data').
+and the batch over ('pod','data').  On one chip the benchmark trains it
+in the ``bbit16-train`` cell (``bench/configs/rcv1-bbit16.json``):
+streaming AdamW through ``run_supervised`` → ``fit_streaming`` on the
+packed logits kernels' factored b = 16 arm.
 """
 import dataclasses
 

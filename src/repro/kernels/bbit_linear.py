@@ -23,9 +23,9 @@ Two input formats share the one-hot contraction:
     n·ceil(k·b/8) bytes HBM→VMEM instead of n·k·4).  An optional
     packed empty bitmask (``np.packbits`` layout, the ``oph_zero``
     shard side file) zeroes the marked bins' one-hot rows, fusing the
-    ragged-mask path that previously forced an XLA gather.  Requires
-    b ∈ {1, 2, 4, 8} so codes never straddle bytes (other b fall back
-    to the XLA unpack path — see ops.py).
+    ragged-mask path that previously forced an XLA gather.  They take
+    b ∈ {1, 2, 4, 8}, where codes never straddle bytes, and b = 16
+    (below); other b fall back to the XLA unpack path (see ops.py).
 
 Layout: rows sit on the 128 lanes and hash functions on sublanes.  The
 wrappers hand the kernels the codes (or packed bytes) transposed,
@@ -41,11 +41,24 @@ block then meets the TPU rule that its last two dimensions are whole
 loop over hash functions, so the packed and widened kernels agree bit
 for bit.
 
-TPU-adaptive dispatch (see ops.py): for 2^b ≤ 4096 the streamed
-one-hot·W matmul reads the whole table at HBM line rate and wins; for
-b = 16 the 2^b·k·C table stream dominates and ops.py falls back to
-XLA's dynamic gather (which is then memory-optimal).  This mirrors the
-classic dense-vs-sparse embedding-lookup tradeoff on TPUs.
+b = 16 is factored.  A (2^16, BN) one-hot per hash function would take
+256 MB for 1,024 rows, so it is never built.  Code j is bytes 2j (lo)
+and 2j+1 (hi) of the packed row, code = 256·hi + lo = 128·r + l with
+r = 2·hi + lo div 128 and l = lo mod 128, and hash function j's slab is
+viewed as W_j[r, l], (C, 512, 128).  In (8, 128) tiles that is the
+table's own row-major order, so handing the slabs to the kernel and
+taking dW back are bitcasts (a 256 × 256 view would cost a relayout of
+the whole table each way).  A (128, BN) one-hot of l and a (512, BN)
+match of r carry the code:
+
+    fwd:  logit[n] += Σ_r 1{r_n = r} · (W_j · onehot_l)[r, n]
+    bwd:  dW_j       += (1{r_n = r} ⊙ dout) · onehot_lᵀ
+
+one (C·512, 128)·(128, BN) MXU product, a mask and a sublane sum one
+way; the lane-contracting product of the b ≤ 8 dW the other.  The
+hash-function block is the outer grid dimension in both directions, so
+the forward reads the table once and the backward writes dW once per
+call; its size follows from the VMEM a table block may take.
 """
 from __future__ import annotations
 
@@ -54,6 +67,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -308,6 +322,142 @@ def _packed_blocks(n: int, k: int, block_n: int, block_j: int):
     return _blocks(n, k, block_n, block_j)
 
 
+# ---------------------------------------------------------------------------
+# b = 16: factored one-hots (module docstring).  The hash-function block
+# is grid dimension 0 in both directions: the forward keeps every row's
+# logits in one resident output block, the backward accumulates each dW
+# block over the row blocks.
+# ---------------------------------------------------------------------------
+_SLAB = (512, 128)             # W_j[r, l], code = 128·r + l
+_ROWS16 = 1024                 # rows a block: lanes of the one-hots
+_TABLE_BLOCK_BYTES = 4 << 20   # one buffer of table (or dW) slabs
+
+
+def _blocks16(n: int, k: int, c: int):
+    """(BN, BJ): BN rows, a multiple of 128; BJ the largest divisor of
+    k whose (BJ, C, 512, 128) f32 slabs fit one table buffer, so the
+    table is never padded (a padded copy would cost a table's read
+    and write per call)."""
+    bn = min(_round_up(n, 128), _ROWS16)
+    cap = max(1, _TABLE_BLOCK_BYTES // (c * _SLAB[0] * _SLAB[1] * 4))
+    bj = max(d for d in range(1, min(k, cap) + 1) if k % d == 0)
+    return bn, bj
+
+
+def _slab_rows(packed, empty, k: int, np_: int):
+    """(k, 2, np_) int32, rows on lanes: hash function j's lane index
+    l = lo mod 128 and row index r = 2·hi + lo div 128 of code
+    256·hi + lo (bytes 2j and 2j+1).  A marked empty bin gets r = 512,
+    which no one-hot row matches; padded rows get code 0 and are sliced
+    away (forward) or carry zero dout (backward)."""
+    n = packed.shape[0]
+    by = packed.astype(jnp.int32).reshape(n, k, 2)
+    lo, hi = by[:, :, 0], by[:, :, 1]
+    r = 2 * hi + (lo >> 7)
+    if empty is not None:
+        j = jnp.arange(k)
+        bit = (empty.astype(jnp.int32)[:, j // 8] >> (7 - j % 8)) & 1
+        r = jnp.where(bit != 0, _SLAB[0], r)
+    rows = jnp.stack([lo & (_SLAB[1] - 1), r], axis=2)
+    rows = jnp.pad(rows, ((0, np_ - n), (0, 0), (0, 0)))
+    return rows.transpose(1, 2, 0)
+
+
+def _slab_onehots(rows_ref, jj):
+    """Hash function jj's (128, BN) lane one-hot (f32) and (512, BN) row
+    match (bool)."""
+    lane = _onehot_t(rows_ref[jj, pl.ds(0, 1), :], _SLAB[1], jnp.float32)
+    row = _onehot_t(rows_ref[jj, pl.ds(1, 1), :], _SLAB[0], jnp.bool_)
+    return lane, row
+
+
+def _fwd16_kernel(bj: int):
+    def kernel(rows_ref, w_ref, out_ref):
+        j, i = pl.program_id(0), pl.program_id(1)
+
+        @pl.when((j == 0) & (i == 0))
+        def _init():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        c, bn = out_ref.shape[1], out_ref.shape[2]
+
+        def step(jj, acc):
+            lane, row = _slab_onehots(rows_ref, jj)
+            w = w_ref[jj].reshape(c * _SLAB[0], _SLAB[1])
+            p = jax.lax.dot_general(
+                w, lane, (((1,), (0,)), ((), ())),
+                precision=_HIGHEST, preferred_element_type=jnp.float32)
+            p = jnp.where(row[None], p.reshape(c, _SLAB[0], bn), 0.0)
+            return acc + jnp.sum(p, axis=1)
+
+        out_ref[i] = jax.lax.fori_loop(0, bj, step, out_ref[i])
+    return kernel
+
+
+def _bwd16_kernel(bj: int):
+    def kernel(rows_ref, dout_ref, dw_ref):
+        @pl.when(pl.program_id(1) == 0)
+        def _init():
+            dw_ref[...] = jnp.zeros_like(dw_ref)
+
+        dout = dout_ref[...]                             # (C, BN)
+        c, bn = dout.shape
+
+        def step(jj, carry):
+            lane, row = _slab_onehots(rows_ref, jj)
+            a = jnp.where(row[None], dout[:, None, :], 0.0)
+            dw_ref[jj] = dw_ref[jj] + jax.lax.dot_general(
+                a.reshape(c * _SLAB[0], bn), lane, (((1,), (1,)), ((), ())),
+                precision=_HIGHEST, preferred_element_type=jnp.float32,
+            ).reshape(c, *_SLAB)
+            return carry
+
+        jax.lax.fori_loop(0, bj, step, 0)
+    return kernel
+
+
+def _packed16_fwd(packed, weights, k, empty, interpret):
+    n = packed.shape[0]
+    c = weights.shape[2]
+    bn, bj = _blocks16(n, k, c)
+    np_ = _round_up(n, bn)
+    rows = _slab_rows(packed, empty, k, np_)
+    w = weights.transpose(0, 2, 1).reshape(k, c, *_SLAB)
+    out = pl.pallas_call(
+        _fwd16_kernel(bj),
+        grid=(k // bj, np_ // bn),
+        in_specs=[pl.BlockSpec((bj, 2, bn), lambda j, i: (j, 0, i)),
+                  pl.BlockSpec((bj, c) + _SLAB, lambda j, i: (j, 0, 0, 0))],
+        out_specs=pl.BlockSpec((np_ // bn, c, bn), lambda j, i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((np_ // bn, c, bn), jnp.float32),
+        interpret=interpret,
+    )(rows, w)
+    return out.transpose(0, 2, 1).reshape(np_, c)[:n]
+
+
+def _packed16_bwd_dw(packed, dout, k, empty, interpret):
+    n, c = dout.shape
+    bn, bj = _blocks16(n, k, c)
+    np_ = _round_up(n, bn)
+    rows = _slab_rows(packed, empty, k, np_)
+    dw = pl.pallas_call(
+        _bwd16_kernel(bj),
+        grid=(k // bj, np_ // bn),
+        in_specs=[pl.BlockSpec((bj, 2, bn), lambda j, i: (j, 0, i)),
+                  pl.BlockSpec((c, bn), lambda j, i: (0, i))],
+        out_specs=pl.BlockSpec((bj, c) + _SLAB, lambda j, i: (j, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, c) + _SLAB, jnp.float32),
+        interpret=interpret,
+    )(rows, _pad_rows_t(dout.astype(jnp.float32), np_))
+    # The kernel wrote dW as (k, C, V) in row-major order.  Said so, the
+    # transpose to (k, V, C) is a bitcast; left to itself, XLA lays the
+    # optimizer update around a relayout of every (k, V, C) state leaf
+    # when k is not a multiple of 8 (k = 500).
+    return with_layout_constraint(
+        dw.reshape(k, c, _SLAB[0] * _SLAB[1]).transpose(0, 2, 1),
+        Layout((0, 2, 1)))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("k", "bits", "block_n", "block_j", "interpret"),
@@ -329,8 +479,11 @@ def bbit_linear_packed_fwd_pallas(
     (tests/test_packed_linear.py property-sweeps b, ragged masks and
     non-lane-multiple k).  ``empty`` (uint8 (n, ceil(k/8)), packbits
     layout) drops the marked bins — the ``oph_zero`` ragged-mask path,
-    fused here instead of falling back to an XLA gather.
+    fused here instead of falling back to an XLA gather.  At b = 16
+    the blocks follow from the shapes (``_blocks16``).
     """
+    if bits == 16:
+        return _packed16_fwd(packed, weights, k, empty, interpret)
     n = packed.shape[0]
     bn, bj, kp = _packed_blocks(n, k, block_n, block_j)
     inputs, specs, scratch = _packed_inputs(packed, empty, k, bits,
@@ -360,7 +513,10 @@ def bbit_linear_packed_bwd_dw_pallas(
 ) -> jax.Array:
     """dW (k, V, C) f32 from packed rows and dout (n, C), in-register
     unpack; ``empty`` bins contribute nothing (their one-hot row is
-    zeroed, matching the forward)."""
+    zeroed, matching the forward).  At b = 16 the blocks follow from
+    the shapes (``_blocks16``)."""
+    if bits == 16:
+        return _packed16_bwd_dw(packed, dout, k, empty, interpret)
     n = packed.shape[0]
     bn, bj, kp = _packed_blocks(n, k, block_n, block_j)
     inputs, specs, scratch = _packed_inputs(packed, empty, k, bits,

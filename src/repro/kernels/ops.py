@@ -7,9 +7,9 @@ Dispatch policy (cost-model driven, see docs/DESIGN.md §2):
                        rest).
   * ``bbit_linear``  — kernel for 2^b ≤ BBIT_KERNEL_MAX_V (one-hot MXU
                        contraction streams the table at line rate);
-                       XLA gather for larger b where the table stream
-                       would dominate.  custom_vjp wires the backward
-                       kernel in.
+                       XLA gather for larger b.  The packed variant
+                       also takes b = 16 on its factored kernels.
+                       custom_vjp wires the backward kernel in.
   * ``vw_sketch``    — kernel for power-of-two buckets, jnp otherwise.
 
 On non-TPU backends (this CPU container) the wrappers run the kernels
@@ -184,13 +184,14 @@ bbit_linear.defvjp(_bbit_linear_vjp_fwd, _bbit_linear_vjp_bwd)
 
 # ---------------------------------------------------------------------------
 def packed_kernel_supported(bits: int, v: int) -> bool:
-    """Whether the packed-input kernels handle (b=bits, V=v): the
-    in-register unpack needs byte-aligned codes, and beyond MAX_V the
-    table stream dominates so the gather fallback is memory-optimal.
-    The single eligibility predicate — models.linear dispatches on it
-    too, so policy changes here cannot diverge from the vjp's own
-    dispatch below."""
-    return bits in PACK_BITS and v <= BBIT_KERNEL_MAX_V
+    """Whether the packed-input kernels handle (b=bits, V=v): codes
+    that never straddle a byte (b ∈ {1, 2, 4, 8}) with V within
+    MAX_V, where the one-hot is built whole, or b = 16, where a lane
+    one-hot and a row match built from the code's two bytes carry it
+    (the factored arm).  Other b take the XLA unpack path.  The same predicate as ``perf``'s eligibility of
+    ``logits_packed``, which models.linear dispatches on, so the two
+    cannot diverge."""
+    return perf.packed_kernel_fits(bits, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))

@@ -53,13 +53,24 @@ def bbit_linear_fwd(codes: jax.Array, weights: jax.Array) -> jax.Array:
     return pairwise_sum(gathered.astype(jnp.float32))
 
 
+def _scatter_dw(codes: jax.Array, dout: jax.Array, vsize: int,
+                keep: jax.Array = None) -> jax.Array:
+    """Σ_n dout[n, c] into dW[j, codes[n, j], c] (where ``keep``): a
+    scatter-add, so no (n, k, V) one-hot exists even at V = 2^16."""
+    n, k = codes.shape
+    d = dout.astype(jnp.float32)
+    upd = jnp.broadcast_to(d[:, None, :], (n, k, d.shape[1]))
+    if keep is not None:
+        upd = jnp.where(keep[:, :, None], upd, 0.0)
+    j = jnp.broadcast_to(jnp.arange(k)[None, :], (n, k))
+    return jnp.zeros((k, vsize, d.shape[1]), jnp.float32).at[
+        j, codes.astype(jnp.int32)].add(upd)
+
+
 def bbit_linear_bwd_dw(codes: jax.Array, dout: jax.Array,
                        vsize: int) -> jax.Array:
     """dW[j, v, c] = Σ_n 1{codes[n,j]=v}·dout[n,c].  Returns (k, V, C) f32."""
-    n, k = codes.shape
-    onehot = jax.nn.one_hot(codes.astype(jnp.int32), vsize,
-                            dtype=jnp.float32)            # (n, k, V)
-    return jnp.einsum("nkv,nc->kvc", onehot, dout.astype(jnp.float32))
+    return _scatter_dw(codes, dout, vsize)
 
 
 def bbit_linear_packed_fwd(packed: jax.Array, weights: jax.Array,
@@ -92,11 +103,8 @@ def bbit_linear_packed_bwd_dw(packed: jax.Array, dout: jax.Array,
     from repro.core.bbit import unpack_codes_jnp, unpack_mask_jnp
 
     codes = unpack_codes_jnp(packed, k, bits).astype(jnp.int32)
-    onehot = jax.nn.one_hot(codes, vsize, dtype=jnp.float32)   # (n, k, V)
-    if empty is not None:
-        mask = unpack_mask_jnp(empty, k)
-        onehot = jnp.where(mask[:, :, None], 0.0, onehot)
-    return jnp.einsum("nkv,nc->kvc", onehot, dout.astype(jnp.float32))
+    keep = None if empty is None else ~unpack_mask_jnp(empty, k)
+    return _scatter_dw(codes, dout, vsize, keep)
 
 
 def vw_sketch(indices: jax.Array, values: jax.Array, nnz: jax.Array,

@@ -138,9 +138,9 @@ def bbit_logits_packed(params, packed: jax.Array, cfg: BBitLinearConfig,
 
     The streaming trainer's forward: minibatches arrive in the on-disk
     packed layout and stay packed.  On the kernel path (TPU, byte-
-    aligned b, 2^b within the table-stream bound) the Pallas kernels
-    unpack b-bit codes in-register, so the (n, k) int32 code matrix of
-    the old ``unpack_codes_jnp`` + ``bbit_logits`` two-step never
+    aligned b with 2^b within the one-hot bound, or b = 16) the Pallas
+    kernels unpack b-bit codes in-register, so the (n, k) int32 code
+    matrix of the old ``unpack_codes_jnp`` + ``bbit_logits`` two-step never
     materializes — and ``empty_packed`` (the ``oph_zero`` bitmask,
     np.packbits layout) is fused into the same pass instead of forcing
     the XLA gather.  Elsewhere it lowers to exactly that two-step

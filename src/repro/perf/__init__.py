@@ -19,6 +19,7 @@ from repro.perf.cost_model import (
     forced,
     get_model,
     maybe_load_profile,
+    packed_kernel_fits,
     reset,
     set_profile,
     shape_bucket,
@@ -30,7 +31,8 @@ __all__ = [
     "BBIT_KERNEL_MAX_V", "ENV_DISPATCH", "ENV_PROFILE", "OPS",
     "CostTable", "ProfileError", "choose", "clear_profile",
     "device_fingerprint", "dispatch_report", "fingerprint_key", "forced",
-    "get_model", "maybe_load_profile", "reset", "set_profile",
+    "get_model", "maybe_load_profile", "packed_kernel_fits", "reset",
+    "set_profile",
     "shape_bucket", "suggest_lane_caps", "suggest_row_buckets",
     "calibrate", "summarize",
 ]

@@ -38,6 +38,10 @@ import jax
 # (k, 2^b) one-hot intermediate stops paying for itself.  Historically
 # lived in kernels/ops.py (which still re-exports it).
 BBIT_KERNEL_MAX_V = 4096
+# The packed kernels' factored arm: a 2^16-entry table per hash
+# function, each code carried by two smaller one-hots
+# (kernels/bbit_linear.py).
+BYTE_FACTORED_BITS = 16
 
 SCHEMA_VERSION = 1
 ENV_DISPATCH = "REPRO_DISPATCH"
@@ -134,10 +138,18 @@ def _logits_eligible(shape) -> Tuple[str, ...]:
     return ("kernel", "gather") if ok else ("gather",)
 
 
+def packed_kernel_fits(b: int, v: int) -> bool:
+    """Whether the packed logits kernels take (b, V = 2^b): whole codes
+    in a byte with V within the one-hot ceiling, or b = 16."""
+    if b == BYTE_FACTORED_BITS:
+        return v == 1 << b
+    return b in _pack_bits() and v <= BBIT_KERNEL_MAX_V
+
+
 def _logits_packed_eligible(shape) -> Tuple[str, ...]:
     b = int(shape.get("b", 0))
     v = int(shape.get("v", (1 << b) if b else (1 << 30)))
-    ok = b in _pack_bits() and v <= BBIT_KERNEL_MAX_V
+    ok = packed_kernel_fits(b, v)
     return ("kernel", "unpack") if ok else ("unpack",)
 
 

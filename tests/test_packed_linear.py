@@ -169,6 +169,63 @@ def test_packed_custom_vjp_grads_match_reference(masked):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("n,k,c,masked", [
+    pytest.param(300, 20, 1, False, id="n300-k20-c1"),
+    pytest.param(300, 20, 1, True, id="n300-k20-c1-masked"),
+    pytest.param(300, 20, 3, False, id="n300-k20-c3"),
+    pytest.param(300, 20, 3, True, id="n300-k20-c3-masked"),
+    pytest.param(1100, 12, 1, True, id="n1100-k12-c1-masked"),
+    pytest.param(17, 37, 1, False, id="n17-k37-c1"),
+])
+def test_packed_b16_kernels_match_reference(n, k, c, masked):
+    """b = 16 takes the factored arm: a 2^16-entry slab per hash
+    function carried by two one-hots.  n = 1100 spans two row blocks,
+    the last ragged (the forward's resident output and dW's sum over
+    the row grid); k = 37, a prime, makes one hash function a block;
+    C = 3 stacks the classes in one product."""
+    _, packed, weights, empty, dout = _case(
+        16, k, n=n, c=c, seed=n + k + c,
+        empty_frac=0.4 if masked else 0.0)
+    v = 1 << 16
+    want = ref.bbit_linear_packed_fwd(packed, weights, k, 16, empty=empty)
+    got = bbit_linear_packed_fwd_pallas(packed, weights, k=k, bits=16,
+                                        empty=empty, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    dwant = ref.bbit_linear_packed_bwd_dw(packed, dout, v, k, 16,
+                                          empty=empty)
+    dgot = bbit_linear_packed_bwd_dw_pallas(packed, dout, v, k=k, bits=16,
+                                            empty=empty, interpret=True)
+    np.testing.assert_allclose(np.asarray(dgot), np.asarray(dwant),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_packed_b16_custom_vjp_takes_the_kernel_arm():
+    from repro import perf
+    k, b = 8, 16
+    _, packed, weights, _, _ = _case(b, k, n=40, c=1)
+    perf.reset()
+
+    def loss_kernel(w):
+        return jnp.sum(ops.bbit_linear_packed(packed, w, k, b) ** 2)
+
+    def loss_ref(w):
+        return jnp.sum(ref.bbit_linear_packed_fwd(packed, w, k, b) ** 2)
+
+    assert ops.packed_kernel_supported(b, 1 << b)
+    jaxpr = jax.make_jaxpr(jax.grad(loss_kernel))(weights)
+    assert str(jaxpr).count("pallas_call") == 2      # forward and dW
+    g = jax.grad(loss_kernel)(weights)
+    chosen = {key: impl for key, impl in
+              perf.dispatch_report()["choices"].items()
+              if key.startswith("logits_packed_bwd|")}
+    assert chosen and all("b=16" in key and impl == "kernel"
+                          for key, impl in chosen.items()), chosen
+    gref = jax.grad(loss_ref)(weights)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(gref),
+                               rtol=1e-4, atol=1e-4)
+
+
 def test_packed_fallback_used_for_non_byte_aligned_b():
     # b=3 codes straddle bytes — dispatch must fall to the XLA path and
     # still match the widened gather exactly

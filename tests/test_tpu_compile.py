@@ -80,21 +80,25 @@ def test_fused_encode_compiles(one_chip, scheme, bits):
     assert compiled.memory_analysis() is not None
 
 
-@pytest.mark.parametrize("direction,c", [
-    pytest.param("fwd", 1, id="fwd"), pytest.param("bwd", 1, id="bwd"),
-    pytest.param("bwd", 3, id="bwd-c3")])
-def test_packed_logits_compile(one_chip, direction, c):
-    bits, v = 8, 256
-    packed = jax.ShapeDtypeStruct((BATCH, K * bits // 8), jnp.uint8,
+@pytest.mark.parametrize("direction,c,bits,k", [
+    pytest.param("fwd", 1, 8, K, id="fwd"),
+    pytest.param("bwd", 1, 8, K, id="bwd"),
+    pytest.param("bwd", 3, 8, K, id="bwd-c3"),
+    # the paper's k=500, b=16 model: the factored arm
+    pytest.param("fwd", 1, 16, 500, id="fwd-b16-k500"),
+    pytest.param("bwd", 1, 16, 500, id="bwd-b16-k500")])
+def test_packed_logits_compile(one_chip, direction, c, bits, k):
+    v = 1 << bits
+    packed = jax.ShapeDtypeStruct((BATCH, k * bits // 8), jnp.uint8,
                                   sharding=one_chip)
     if direction == "fwd":
         fn = lambda p, w: bbit_linear.bbit_linear_packed_fwd_pallas(  # noqa
-            p, w, k=K, bits=bits)
-        other = jax.ShapeDtypeStruct((K, v, c), jnp.float32,
+            p, w, k=k, bits=bits)
+        other = jax.ShapeDtypeStruct((k, v, c), jnp.float32,
                                      sharding=one_chip)
     else:
         fn = lambda p, d: bbit_linear.bbit_linear_packed_bwd_dw_pallas(  # noqa
-            p, d, v, k=K, bits=bits)
+            p, d, v, k=k, bits=bits)
         other = jax.ShapeDtypeStruct((BATCH, c), jnp.float32,
                                      sharding=one_chip)
     _, text = _compile(fn, packed, other)
