@@ -55,10 +55,14 @@ match of r carry the code:
     bwd:  dW_j       += (1{r_n = r} ⊙ dout) · onehot_lᵀ
 
 one (C·512, 128)·(128, BN) MXU product, a mask and a sublane sum one
-way; the lane-contracting product of the b ≤ 8 dW the other.  The
-hash-function block is the outer grid dimension in both directions, so
-the forward reads the table once and the backward writes dW once per
-call; its size follows from the VMEM a table block may take.
+way; the lane-contracting product of the b ≤ 8 dW the other.  The f32
+operand of each product (the slab, dout) goes to the MXU as three bf16
+parts that sum to it exactly (``_split3``), against the 0/1 operand in
+bf16, which is exact: three default-precision passes where ``_HIGHEST``
+takes six.  The hash-function block is the outer grid dimension in both
+directions, so the forward reads the table once and the backward writes
+dW once per call; its size follows from the VMEM a table block may
+take.
 """
 from __future__ import annotations
 
@@ -363,15 +367,44 @@ def _slab_rows(packed, empty, k: int, np_: int):
     return rows.transpose(1, 2, 0)
 
 
-def _slab_onehots(rows_ref, jj):
-    """Hash function jj's (128, BN) lane one-hot (f32) and (512, BN) row
-    match (bool)."""
-    lane = _onehot_t(rows_ref[jj, pl.ds(0, 1), :], _SLAB[1], jnp.float32)
-    row = _onehot_t(rows_ref[jj, pl.ds(1, 1), :], _SLAB[0], jnp.bool_)
-    return lane, row
+def _split3(x):
+    """f32 → three bf16 parts whose f32 sum (hi + mid) + lo is x, bit for
+    bit.
+
+    A 24-bit significand is three 8-bit ones and bf16 keeps f32's
+    exponent range, so each residual fits the next part whole, for
+    2^-103 ≤ |x| < 2^127 and ±0 (below, ``lo`` would be subnormal).
+    The residuals are taken as -(a - b), which is b - a but for the
+    sign of a zero, so -0 splits into three -0.  Against an exact bf16
+    operand (a one-hot, a 0/1 match) three default-precision MXU passes
+    give what six at ``_HIGHEST`` give; a two-part split,
+    ``Precision.HIGH``'s, keeps 16 bits of x and is not exact."""
+    f32 = jnp.float32
+    hi = x.astype(jnp.bfloat16)
+    r = -(hi.astype(f32) - x)
+    mid = r.astype(jnp.bfloat16)
+    lo = (-(mid.astype(f32) - r)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _slab_onehots(rows_ref, jj, lane_dtype, row_dtype):
+    """Hash function jj's (128, BN) lane one-hot and (512, BN) row
+    match, bool or bf16.  A bf16 one is a select of 1.0 in f32, packed
+    (0/1 is exact): Mosaic lowers that without the int → float
+    conversion that ``astype`` of a compare takes."""
+    def onehot(i, v, dtype):
+        hit = _onehot_t(rows_ref[jj, pl.ds(i, 1), :], v, jnp.bool_)
+        if dtype == jnp.bool_:
+            return hit
+        return jnp.where(hit, 1.0, 0.0).astype(dtype)
+    return onehot(0, _SLAB[1], lane_dtype), onehot(1, _SLAB[0], row_dtype)
 
 
 def _fwd16_kernel(bj: int):
+    """The slab's three bf16 parts side by side, (C·512, 3·128), against
+    the lane one-hot stacked three times, (3·128, BN): one product whose
+    contraction adds hi·1 + mid·1 + lo·1, so each entry is its W entry
+    exactly and the logits are those of an f32 gather."""
     def kernel(rows_ref, w_ref, out_ref):
         j, i = pl.program_id(0), pl.program_id(1)
 
@@ -382,11 +415,13 @@ def _fwd16_kernel(bj: int):
         c, bn = out_ref.shape[1], out_ref.shape[2]
 
         def step(jj, acc):
-            lane, row = _slab_onehots(rows_ref, jj)
+            lane, row = _slab_onehots(rows_ref, jj, jnp.bfloat16, jnp.bool_)
             w = w_ref[jj].reshape(c * _SLAB[0], _SLAB[1])
             p = jax.lax.dot_general(
-                w, lane, (((1,), (0,)), ((), ())),
-                precision=_HIGHEST, preferred_element_type=jnp.float32)
+                jnp.concatenate(_split3(w), axis=1),
+                jnp.concatenate([lane] * 3, axis=0),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
             p = jnp.where(row[None], p.reshape(c, _SLAB[0], bn), 0.0)
             return acc + jnp.sum(p, axis=1)
 
@@ -395,21 +430,33 @@ def _fwd16_kernel(bj: int):
 
 
 def _bwd16_kernel(bj: int):
+    """dout's three bf16 parts are split once a row block.  Per hash
+    function the row match (512, BN) meets the lane one-hot times each
+    part of each class, (C·3·128, BN), over the lanes of both; the
+    three 128-lane slices of a class's (512, 3·128) product sum to its
+    dW slab.  The only (512, BN) element-wise work is the match."""
     def kernel(rows_ref, dout_ref, dw_ref):
         @pl.when(pl.program_id(1) == 0)
         def _init():
             dw_ref[...] = jnp.zeros_like(dw_ref)
 
-        dout = dout_ref[...]                             # (C, BN)
-        c, bn = dout.shape
+        parts = [x.astype(jnp.float32)                   # 3 × (C, BN)
+                 for x in _split3(dout_ref[...])]
+        c, l = dout_ref.shape[0], _SLAB[1]
 
         def step(jj, carry):
-            lane, row = _slab_onehots(rows_ref, jj)
-            a = jnp.where(row[None], dout[:, None, :], 0.0)
-            dw_ref[jj] = dw_ref[jj] + jax.lax.dot_general(
-                a.reshape(c * _SLAB[0], bn), lane, (((1,), (1,)), ((), ())),
-                precision=_HIGHEST, preferred_element_type=jnp.float32,
-            ).reshape(c, *_SLAB)
+            lane, row = _slab_onehots(rows_ref, jj, jnp.bool_, jnp.bfloat16)
+            scaled = jnp.concatenate(                   # (C·3·128, BN)
+                [jnp.where(lane, part[cc:cc + 1], 0.0).astype(jnp.bfloat16)
+                 for cc in range(c) for part in parts], axis=0)
+            p = jax.lax.dot_general(                    # (512, C·3·128)
+                row, scaled, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dw_ref[jj] = dw_ref[jj] + jnp.stack([
+                p[:, (3 * cc) * l:(3 * cc + 1) * l]
+                + p[:, (3 * cc + 1) * l:(3 * cc + 2) * l]
+                + p[:, (3 * cc + 2) * l:(3 * cc + 3) * l]
+                for cc in range(c)])
             return carry
 
         jax.lax.fori_loop(0, bj, step, 0)

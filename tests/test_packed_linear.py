@@ -7,6 +7,8 @@ kernels (identical contraction order, only the input format differs);
 vs the gather reference — a mathematically equal but differently
 associated sum — they are allclose, matching the tolerance the widened
 kernels themselves are validated to in test_kernels.py."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -16,6 +18,7 @@ import jax.numpy as jnp
 from repro.core.bbit import pack_codes, unpack_codes_jnp
 from repro.kernels import ops, ref
 from repro.kernels.bbit_linear import (
+    _split3,
     bbit_linear_bwd_dw_pallas,
     bbit_linear_fwd_pallas,
     bbit_linear_packed_bwd_dw_pallas,
@@ -224,6 +227,135 @@ def test_packed_b16_custom_vjp_takes_the_kernel_arm():
     gref = jax.grad(loss_ref)(weights)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gref),
                                rtol=1e-4, atol=1e-4)
+
+
+def _two_part_split(x):
+    """``Precision.HIGH``'s split: bf16(x) and bf16 of the rest."""
+    hi = x.astype(jnp.bfloat16)
+    return hi, (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _full_mantissa(x):
+    """float32 ``x`` with its last significand bit set: all 24 bits
+    count."""
+    return (np.asarray(x, np.float32).view(np.uint32) | 1).view(np.float32)
+
+
+@pytest.mark.parametrize("parts", ["three", "two"])
+def test_split3_parts_sum_to_x_bitwise(parts):
+    """hi + mid + lo gives x back bit for bit over f32's exponent range,
+    signs and ±0; the two-part split of ``Precision.HIGH`` misses nearly
+    every value whose 24 bits are all needed (it keeps those whose
+    middle bits happen to be zero)."""
+    rng = np.random.default_rng(17)
+    x = _full_mantissa(rng.uniform(1, 2, 20000)
+                       * np.exp2(rng.integers(-100, 126, 20000))
+                       * rng.choice([-1.0, 1.0], 20000))
+    edges = np.array([0.0, -0.0, 1 + 2 ** -23, -(2 - 2 ** -23), 2 ** -103,
+                      -(2 - 2 ** -23) * 2 ** 126, 1.0, -3.0], np.float32)
+    x = np.concatenate([x, edges])
+    split = _split3 if parts == "three" else _two_part_split
+    got = functools.reduce(
+        lambda a, b: a + b,
+        [p.astype(jnp.float32) for p in jax.jit(split)(jnp.asarray(x))])
+    same = np.asarray(got).view(np.uint32) == x.view(np.uint32)
+    if parts == "three":
+        assert same.all(), x[~same][:5]
+    else:
+        assert same[:-len(edges)].mean() < 0.02
+
+
+def _sequential_logits(codes, weights, live):
+    """Σ_j W[j, code_j] in f32, one add a hash function in order j =
+    0, 1, …: the forward kernel's order."""
+    acc = np.zeros((codes.shape[0], weights.shape[2]), np.float32)
+    for j in range(codes.shape[1]):
+        acc = acc + np.where(live[:, j:j + 1],
+                             weights[j, codes[:, j].astype(np.int64)], 0.0
+                             ).astype(np.float32)
+    return acc
+
+
+@pytest.mark.parametrize("c,masked", [
+    pytest.param(1, False, id="c1"), pytest.param(3, False, id="c3"),
+    pytest.param(1, True, id="c1-two-live"),
+    pytest.param(3, True, id="c3-two-live")])
+def test_packed_b16_forward_is_exact(c, masked):
+    """Weights with all 24 significand bits: the forward equals the f32
+    sum of the rows' entries bit for bit, in the kernel's order, and
+    the reference's bit for bit where each row keeps at most two live
+    bins (one rounding, the same in any order).  Any bit of W that the
+    bf16 parts dropped would show."""
+    n, k = 300, 20
+    codes, packed, weights, _, _ = _case(16, k, n=n, c=c, seed=41 + c)
+    weights = jnp.asarray(_full_mantissa(weights))
+    empty, live = None, np.ones((n, k), bool)
+    if masked:
+        rng = np.random.default_rng(c)
+        live = np.zeros((n, k), bool)
+        rows = np.arange(n)
+        live[rows, rng.integers(0, k, n)] = True
+        live[rows[::2], rng.integers(0, k, n)[::2]] = True
+        live[5] = False                                 # an all-empty row
+        empty = jnp.asarray(np.packbits(~live, axis=1))
+    got = np.asarray(bbit_linear_packed_fwd_pallas(
+        packed, weights, k=k, bits=16, empty=empty, interpret=True))
+    np.testing.assert_array_equal(
+        got, _sequential_logits(codes, np.asarray(weights), live))
+    if masked:
+        np.testing.assert_array_equal(
+            got, np.asarray(ref.bbit_linear_packed_fwd(packed, weights, k,
+                                                       16, empty=empty)))
+
+
+@pytest.mark.parametrize("c,masked", [
+    pytest.param(1, False, id="c1"), pytest.param(3, True, id="c3-masked")])
+def test_packed_b16_dw_sums_to_float64(c, masked):
+    """Each hash function draws its codes from 11 values, so a dW entry
+    sums ~100 douts over two row blocks, their magnitudes spread over
+    2^40 so that the largest few carry the sum.  Against a float64 sum
+    dW errs by f32 rounding alone, under 1e-6 of Σ|dout|; a 16-bit
+    split of dout misses by ~2e-6."""
+    n, k, v = 1100, 12, 1 << 16
+    rng = np.random.default_rng(5 + c)
+    values = rng.choice(v, size=(k, 11), replace=False)
+    codes = values[np.arange(k), rng.integers(0, 11, (n, k))]
+    dout = _full_mantissa(rng.choice([-1.0, 1.0], (n, c))
+                          * rng.uniform(1, 2, (n, c))
+                          * np.exp2(rng.uniform(-40, 0, (n, c))))
+    live = rng.random((n, k)) > 0.3 if masked else np.ones((n, k), bool)
+    empty = jnp.asarray(np.packbits(~live, axis=1)) if masked else None
+    got = np.asarray(bbit_linear_packed_bwd_dw_pallas(
+        jnp.asarray(pack_codes(codes.astype(np.uint16), 16)),
+        jnp.asarray(dout), v, k=k, bits=16, empty=empty, interpret=True))
+    want, scale = np.zeros((k, v, c)), np.zeros((k, v, c))
+    for j in range(k):
+        np.add.at(want[j], codes[live[:, j], j], dout[live[:, j]])
+        np.add.at(scale[j], codes[live[:, j], j], np.abs(dout[live[:, j]]))
+    hit = scale > 0
+    assert not got[~hit].any()
+    np.testing.assert_array_less(np.abs(got - want)[hit], 1e-6 * scale[hit])
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_b16_kernels_multiply_bf16_on_the_mxu(direction):
+    """The b = 16 loop bodies multiply bf16 by bf16 at default precision
+    (three exact passes), never f32 by f32 (six at ``_HIGHEST``)."""
+    k, n = 500, 1024
+    packed = jax.ShapeDtypeStruct((n, 2 * k), jnp.uint8)
+    if direction == "fwd":
+        jaxpr = jax.make_jaxpr(lambda p, w: bbit_linear_packed_fwd_pallas(
+            p, w, k=k, bits=16))(
+            packed, jax.ShapeDtypeStruct((k, 1 << 16, 1), jnp.float32))
+    else:
+        jaxpr = jax.make_jaxpr(lambda p, d: bbit_linear_packed_bwd_dw_pallas(
+            p, d, 1 << 16, k=k, bits=16))(
+            packed, jax.ShapeDtypeStruct((n, 1), jnp.float32))
+    dots = [e for e in _kernel_eqns(jaxpr.jaxpr)
+            if e.primitive.name == "dot_general"]
+    assert len(dots) == 1
+    assert [x.aval.dtype for x in dots[0].invars] == [jnp.bfloat16] * 2
+    assert dots[0].params["precision"] in (None, (None, None))
 
 
 def test_packed_fallback_used_for_non_byte_aligned_b():
