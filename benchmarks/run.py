@@ -5,10 +5,9 @@ Prints ``name,us_per_call,derived`` CSV.  QUICK grids by default;
 ``python -m benchmarks.run fig1 fig8 table2``.
 
 ``python -m benchmarks.run --smoke`` is the CI tier: tiny shapes
-(``BENCH_SMOKE=1``), interpret-mode fused-kernel parity canaries, a
-preprocessing-pipeline parity pass, and a budget-capped cost-model
-calibration + profile round-trip (dispatch_preprocess) — fast enough
-for every merge, and any bit mismatch fails the run.  Smoke mode never
+(``BENCH_SMOKE=1``), interpret-mode fused-kernel parity canaries and a
+preprocessing-pipeline parity pass — fast enough for every merge, and
+any bit mismatch fails the run.  Smoke mode never
 writes trajectory JSON files.
 
 OPH suites write ``BENCH_oph.json``, the preprocess suite writes
@@ -28,13 +27,13 @@ import traceback
 
 # Suites whose records feed the perf-trajectory files.
 OPH_SUITES = ("kernels_oph", "oph_curve")
-PREPROCESS_SUITES = ("preprocess", "dispatch_preprocess")
+PREPROCESS_SUITES = ("preprocess",)
 STREAMING_SUITES = ("streaming", "multihost")
-SERVING_SUITES = ("serving", "dispatch_serving")
+SERVING_SUITES = ("serving",)
 RETRIEVAL_SUITES = ("retrieval",)
 
 SMOKE_DEFAULT = ["kernels_fused", "preprocess", "streaming", "serving",
-                 "retrieval", "dispatch_preprocess"]
+                 "retrieval"]
 
 
 def _write_json(path_env: str, default: str, bench: str, records) -> None:
@@ -58,7 +57,7 @@ def main() -> None:
         argv = [a for a in argv if a != "--smoke"]
         os.environ["BENCH_SMOKE"] = "1"   # before benchmarks.* imports
 
-    from benchmarks import (dispatch_bench, kernel_bench, paper_figures,
+    from benchmarks import (kernel_bench, paper_figures,
                             preprocess_bench, retrieval_bench,
                             roofline_report, serving_bench,
                             streaming_bench)
@@ -84,8 +83,6 @@ def main() -> None:
         "multihost": streaming_bench.multihost_bench,
         "serving": serving_bench.serving_bench,
         "retrieval": retrieval_bench.retrieval_bench,
-        "dispatch_preprocess": dispatch_bench.dispatch_preprocess_bench,
-        "dispatch_serving": dispatch_bench.dispatch_serving_bench,
     }
     if argv:
         selected = argv

@@ -17,9 +17,10 @@ library entry points a user calls, all in this one process:
          logits within ``SCORE_RTOL``/``SCORE_ATOL``.
 
 Each phase prints one JSON line: the device, the dispatch arms
-``perf`` chose, and how many Pallas kernels (``tpu_custom_call``) the
-compiled encode / train-step / serving HLO holds.  On a TPU a phase
-fails if a hot op fell back off its kernel arm.  The last line is
+``repro.kernels.dispatch`` chose, and how many Pallas kernels
+(``tpu_custom_call``) the compiled encode / train-step / serving HLO
+holds.  On a TPU a phase fails if a hot op fell back off its kernel
+arm.  The last line is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
 Any backend but a TPU fails before work starts.
 
@@ -78,10 +79,11 @@ def _device() -> dict:
 
 
 def _arms() -> dict:
-    """op → the impls ``perf`` chose since the last ``perf.reset``."""
-    from repro import perf
+    """op → the impls ``dispatch`` chose since the last
+    ``dispatch.reset``."""
+    from repro.kernels import dispatch
     out: dict = {}
-    for key, impl in perf.dispatch_report()["choices"].items():
+    for key, impl in dispatch.dispatch_report()["choices"].items():
         out.setdefault(key.split("|")[0], set()).add(impl)
     return {op: sorted(impls) for op, impls in sorted(out.items())}
 
@@ -139,11 +141,11 @@ def phase_hash(workdir: str, sizes: Sizes, seed: int,
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro import perf
+    from repro.kernels import dispatch
     from repro.core.schemes import make_scheme
     from repro.data import load_packed_shard, preprocess_rows_packed
 
-    perf.reset()
+    dispatch.reset()
     rows, labels, root, stats = build_archive(workdir, sizes, seed)
     n_train = sizes.n_docs - sizes.held_out
 
@@ -220,13 +222,13 @@ def phase_train(workdir: str, sizes: Sizes, seed: int, root: str,
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro import perf
+    from repro.kernels import dispatch
     from repro.configs.rcv1_oph import CONFIG
     from repro.data import load_packed_shard
     from repro.models.linear import bbit_logits_packed
     from repro.train.losses import mean_loss_fn
 
-    perf.reset()
+    dispatch.reset()
     sup = _fit(root, os.path.join(workdir, "ckpt"), sizes, seed)
     res = sup.result
     if sup.restarts or sup.crashes:
@@ -302,11 +304,11 @@ def phase_serve(sizes: Sizes, seed: int, rows, params,
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro import perf
+    from repro.kernels import dispatch
     from repro.core.schemes import make_scheme
     from repro.kernels import ref
 
-    perf.reset()
+    dispatch.reset()
     docs = _serve_docs(rows, sizes)
     engine, got, stats = _engine_scores(params, sizes, seed, docs, 1)
 

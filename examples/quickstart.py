@@ -22,21 +22,7 @@ or from the command line:
         --mode classifier --http --port 8077
     curl -s localhost:8077/status | python -m json.tool
 
-Calibrate once, run fast (step 6 here): every implementation choice —
-Pallas kernel vs XLA fallback, packed logits kernel vs unpack, serving
-micro-batch sizing — routes through ``repro.perf``.  Measure this box
-once and every launcher picks the measured winner:
-
-    PYTHONPATH=src python -m repro.launch.calibrate \
-        --out artifacts/perf/profile.json --budget-s 60
-    PYTHONPATH=src python -m repro.launch.train --mode stream \
-        --profile artifacts/perf/profile.json
-    # or: export REPRO_PROFILE=artifacts/perf/profile.json
-
-No profile (or a profile from a different machine) is always safe: the
-static heuristics this repo has always shipped apply, bit-identically.
-
-Duplicate traffic (step 7 here): repeat documents short-circuit
+Duplicate traffic (step 6 here): repeat documents short-circuit
 through the minhash-keyed score cache — band-signature probe, exact
 packed-code guard, scores bitwise-identical to a fresh dispatch.  Full
 HTTP tour (``GET /status`` dedup counters, hot-reload invalidation) in
@@ -104,26 +90,7 @@ def main() -> None:
     srv.request_drain()               # drains the engine too
     srv.wait_finished(timeout=30)
 
-    print("6) calibrate once, run fast: measuring this box's dispatch "
-          "cost table (budget-capped)…")
-    import tempfile
-
-    from repro import perf
-    table = perf.calibrate(k=k, b_values=(b,), schemes=("minwise",),
-                           encode_rows=(32,), encode_widths=(128,),
-                           logits_rows=(64,), include_serving=False,
-                           trials=2, budget_s=15.0)
-    with tempfile.TemporaryDirectory() as td:
-        path = f"{td}/profile.json"
-        table.save(path)                      # versioned, device-keyed
-        perf.maybe_load_profile(path)         # what --profile does
-        rep = perf.dispatch_report()
-    print(f"   {len(table.entries)} measured entries in "
-          f"{table.meta['calibrate_seconds']}s; dispatch now profile-"
-          f"driven (table {rep['table_version']!r}) — wrong-device or "
-          f"missing profiles fall back to the static heuristics")
-
-    print("7) duplicate traffic: the minhash-keyed score cache "
+    print("6) duplicate traffic: the minhash-keyed score cache "
           "(full HTTP demo in examples/serve_classifier.py)…")
     dedup_eng = HashedClassifierEngine(res.params, lcfg, seed=1,
                                        nnz_buckets=(2048, 8192),

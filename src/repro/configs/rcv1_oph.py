@@ -90,16 +90,6 @@ class OPHPaperConfig:
     stream_procs: int = 1
     ft_barrier_timeout_s: float = 120.0
     stream_grad_compress: Optional[int] = None
-    # cost-model dispatch (PR 8): a measured perf profile consumed by
-    # launch/train.py, launch/serve.py and the benchmarks — "calibrate
-    # once, run fast" (launch/calibrate.py writes it; a missing or
-    # wrong-device file silently degrades to the static heuristics) —
-    # and the calibration pass's own knobs
-    profile_path: str = "artifacts/perf/profile.json"
-    calibrate_budget_s: float = 60.0
-    calibrate_trials: int = 3
-    calibrate_max_batch: int = 64
-    calibrate_nnz_buckets: tuple = (128, 512, 2048)
     # duplicate-traffic dedup cache + LSH retrieval (PR 9): the serving
     # engine's band-keyed score cache (serving/dedup.py — probe on
     # dedup_probe_bands band keys, guard on exact packed-code equality,
@@ -172,18 +162,6 @@ class OPHPaperConfig:
                   dedup_entries=self.dedup_entries,
                   dedup_rows_per_band=self.dedup_rows_per_band,
                   dedup_probe_bands=self.dedup_probe_bands)
-        kw.update(overrides)
-        return kw
-
-    def calibrate_kwargs(self, **overrides) -> dict:
-        """Keyword arguments for ``perf.calibrate`` at this config's
-        scale — the one-shot microbenchmark pass behind
-        ``launch/calibrate.py``."""
-        kw = dict(k=self.k, b_values=(self.b,), schemes=(self.scheme,),
-                  max_batch=self.calibrate_max_batch,
-                  nnz_buckets=self.calibrate_nnz_buckets,
-                  trials=self.calibrate_trials,
-                  budget_s=self.calibrate_budget_s, seed=self.seed)
         kw.update(overrides)
         return kw
 

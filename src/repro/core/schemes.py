@@ -162,7 +162,7 @@ class HashingScheme:
         """Hash evaluations issued per nonzero (the Table-2 cost driver)."""
         raise NotImplementedError
 
-    # -- dispatch (routed through the perf cost model) ----------------------
+    # -- dispatch (routed through repro.kernels.dispatch) --------------------
 
     def _encode_shape(self, indices, b: int) -> dict:
         return {"scheme": self.name, "k": self.k, "b": int(b),
@@ -172,10 +172,9 @@ class HashingScheme:
     def _choose_encode(self, indices, b: int, use_kernel: bool) -> str:
         """Kernel-vs-XLA choice for unpacked encode.  ``use_kernel=False``
         pins the XLA arm (the historical contract); True defers to
-        ``perf.choose`` — heuristic (TPU→Pallas) unless a profile says
-        otherwise."""
-        from repro import perf
-        return perf.choose("encode", self._encode_shape(indices, b),
+        ``dispatch.choose`` (TPU→Pallas)."""
+        from repro.kernels import dispatch
+        return dispatch.choose("encode", self._encode_shape(indices, b),
                            impl=None if use_kernel else "xla")
 
     def _fused_pack(self, indices, b: int, use_kernel: bool = True) -> bool:

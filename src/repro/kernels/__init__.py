@@ -6,7 +6,8 @@
   bbit_linear  — fused one-hot-expansion linear fwd/bwd (paper §3)
   vw_sketch    — VW signed feature hashing (paper §5.2)
 
-Import ``repro.kernels.ops`` for the dispatching public API and
+Import ``repro.kernels.ops`` for the dispatching public API,
+``repro.kernels.dispatch`` for the kernel choice behind it and
 ``repro.kernels.ref`` for the pure-jnp oracles.
 """
 from repro.kernels import ops, ref

@@ -1,6 +1,6 @@
 """Public jit'd wrappers around the Pallas kernels with jnp fallbacks.
 
-Dispatch policy (cost-model driven, see docs/DESIGN.md §2):
+Dispatch policy (``repro.kernels.dispatch``, docs/DESIGN.md §2):
   * ``minhash``      — kernel always (pure VPU streaming).
   * ``oph``          — kernel always (single-pass scatter-min; k must be
                        a power of two — the core jnp path covers the
@@ -16,10 +16,10 @@ On non-TPU backends (this CPU container) the wrappers run the kernels
 in interpret mode when ``interpret=None`` (auto) — the same code path a
 TPU deployment exercises, minus Mosaic lowering.
 
-Every branch here is a thin client of ``perf.choose`` — the measured
-cost-model dispatch layer.  Without a loaded profile the choices are
-bit-identical to the historical static policy; with one, each
-(op, shape-bucket) picks whichever arm actually measured faster.
+Every branch here is a thin client of ``dispatch.choose``: kernels on
+a TPU, XLA arms elsewhere at the scheme and model layers, unless a
+caller, a test pin or ``REPRO_DISPATCH`` asks for the other eligible
+arm.
 """
 from __future__ import annotations
 
@@ -29,7 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
+from repro.kernels import dispatch, ref
 from repro.kernels.minhash import minhash_pallas
 from repro.kernels.oph import oph_pallas
 from repro.kernels.fused_encode import (
@@ -48,8 +48,7 @@ from repro.kernels.hamming import (
     hamming_distance_xla,
 )
 from repro.kernels.vw_sketch import vw_sketch_pallas
-from repro import perf
-from repro.perf import BBIT_KERNEL_MAX_V  # canonical home is perf; noqa
+from repro.kernels.dispatch import BBIT_KERNEL_MAX_V, packed_kernel_fits
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
@@ -58,7 +57,7 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
     other backends always interpret."""
     impl = None if interpret is None else (
         "interpret" if interpret else "compiled")
-    return perf.choose("pallas_mode", impl=impl) != "compiled"
+    return dispatch.choose("pallas_mode", impl=impl) != "compiled"
 
 
 # ---------------------------------------------------------------------------
@@ -99,15 +98,14 @@ def fused_encode_on_device(bits: int, *, scheme: Optional[str] = None,
                            rows: Optional[int] = None,
                            nnz: Optional[int] = None,
                            impl: Optional[str] = None) -> bool:
-    """THE dispatch predicate for the fused encode kernels — now a thin
-    client of ``perf.choose("encode_packed", ...)``.
+    """THE dispatch predicate for the fused encode kernels — a thin
+    client of ``dispatch.choose("encode_packed", ...)``.
     ``schemes.encode_packed_device`` (offline preprocessing) and
     ``schemes.encode_packed_jit`` (the serving engine's jitted
     encode→score pass) both branch on it, so the serving hot path can
-    never diverge from the preprocessing dispatch policy.  Without a
-    profile this reproduces the old static predicate exactly: TPU
-    backend AND byte-aligned b (interpret-mode Pallas on CPU would
-    crawl; XLA covers it)."""
+    never diverge from the preprocessing dispatch policy: TPU backend
+    AND byte-aligned b (interpret-mode Pallas on CPU would crawl; XLA
+    covers it)."""
     shape = {"b": int(bits)}
     if scheme is not None:
         shape["scheme"] = scheme
@@ -117,7 +115,7 @@ def fused_encode_on_device(bits: int, *, scheme: Optional[str] = None,
         shape["rows"] = int(rows)
     if nnz is not None:
         shape["nnz"] = int(nnz)
-    return perf.choose("encode_packed", shape, impl=impl) == "pallas"
+    return dispatch.choose("encode_packed", shape, impl=impl) == "pallas"
 
 
 def minhash_packed(indices, nnz, a, b, bits: int,
@@ -170,7 +168,7 @@ def _bbit_linear_vjp_bwd(interpret, res, dout):
     codes, weights = res
     v = weights.shape[1]
     shape = {"v": v, "k": codes.shape[1], "rows": codes.shape[0]}
-    if perf.choose("logits_bwd", shape) == "kernel":
+    if dispatch.choose("logits_bwd", shape) == "kernel":
         dw = bbit_linear_bwd_dw_pallas(
             codes.astype(jnp.int32), dout.astype(jnp.float32), v,
             interpret=_auto_interpret(interpret))
@@ -183,17 +181,6 @@ bbit_linear.defvjp(_bbit_linear_vjp_fwd, _bbit_linear_vjp_bwd)
 
 
 # ---------------------------------------------------------------------------
-def packed_kernel_supported(bits: int, v: int) -> bool:
-    """Whether the packed-input kernels handle (b=bits, V=v): codes
-    that never straddle a byte (b ∈ {1, 2, 4, 8}) with V within
-    MAX_V, where the one-hot is built whole, or b = 16, where a lane
-    one-hot and a row match built from the code's two bytes carry it
-    (the factored arm).  Other b take the XLA unpack path.  The same predicate as ``perf``'s eligibility of
-    ``logits_packed``, which models.linear dispatches on, so the two
-    cannot diverge."""
-    return perf.packed_kernel_fits(bits, v)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _bbit_linear_packed(k, bits, interpret, packed, empty, weights):
     return _bbit_linear_packed_fwd_impl(k, bits, interpret, packed,
@@ -202,7 +189,7 @@ def _bbit_linear_packed(k, bits, interpret, packed, empty, weights):
 
 def _bbit_linear_packed_fwd_impl(k, bits, interpret, packed, empty,
                                  weights):
-    if packed_kernel_supported(bits, weights.shape[1]):
+    if packed_kernel_fits(bits, weights.shape[1]):
         return bbit_linear_packed_fwd_pallas(
             packed, weights, k=k, bits=bits, empty=empty,
             interpret=_auto_interpret(interpret))
@@ -221,7 +208,7 @@ def _bbit_linear_packed_vjp_bwd(k, bits, interpret, res, dout):
     packed, empty, weights = res
     v = weights.shape[1]
     shape = {"v": v, "k": k, "b": bits, "rows": packed.shape[0]}
-    if perf.choose("logits_packed_bwd", shape) == "kernel":
+    if dispatch.choose("logits_packed_bwd", shape) == "kernel":
         dw = bbit_linear_packed_bwd_dw_pallas(
             packed, dout.astype(jnp.float32), v, k=k, bits=bits,
             empty=empty, interpret=_auto_interpret(interpret))
@@ -261,14 +248,14 @@ def hamming_topk(query, cands, *, k: int, bits: int, topk: int,
     (idx int32 (t,), sims f32 (t,)) with t = min(topk, n), sims sorted
     descending: sim = 1 − popcount_dist/(k·bits), the fraction of
     agreeing code bits.  Distance arm routed through
-    ``perf.choose("hamming_topk")`` — Pallas SWAR popcount vs XLA
+    ``dispatch.choose("hamming_topk")`` — Pallas SWAR popcount vs XLA
     ``population_count`` (bit-identical integers, so the choice can
     never change results).
     """
     n = int(cands.shape[0])
     shape = {"b": int(bits), "k": int(k), "rows": n,
              "width": int(cands.shape[1])}
-    if perf.choose("hamming_topk", shape, impl=impl) == "pallas":
+    if dispatch.choose("hamming_topk", shape, impl=impl) == "pallas":
         dist = hamming_distance_pallas(query, cands,
                                        interpret=_auto_interpret(interpret))
     else:

@@ -50,14 +50,7 @@ def _build_classifier_engine(args):
                                codes[n_tr:], labels[n_tr:], lcfg,
                                loss="logistic", C=1.0, max_iter=25)
     print(f"model ready: test acc {res.test_acc:.3f}")
-    from repro import perf
     from repro.configs.rcv1_oph import CONFIG
-    profile = args.profile if args.profile is not None \
-        else CONFIG.profile_path
-    has_profile = perf.maybe_load_profile(profile)
-    print("dispatch: "
-          + (f"cost-model profile {profile}" if has_profile
-             else "static heuristics (no usable profile)"))
     dedup_kw = {}
     if args.dedup_cache:
         dedup_kw = CONFIG.dedup_kwargs(dedup_cache=True,
@@ -65,10 +58,7 @@ def _build_classifier_engine(args):
     eng = HashedClassifierEngine(
         res.params, lcfg, seed=1, max_batch=args.max_batch,
         nnz_buckets=(2048, 8192),
-        # with a measured profile the engine derives per-lane row
-        # buckets + drain caps from the serve_score cost curve;
-        # without one this is the historical static pair
-        row_buckets=None if has_profile else (1, args.max_batch),
+        row_buckets=(1, args.max_batch),
         adapt_every=args.adapt_every, **dedup_kw)
     if args.dedup_cache:
         print(f"DEDUP_CACHE entries={args.cache_entries} "
@@ -167,10 +157,6 @@ def main() -> None:
     ap.add_argument("--cache-entries", type=int, default=None,
                     help="dedup cache capacity (LRU entries; default: "
                          "the config's dedup_entries)")
-    ap.add_argument("--profile", default=None,
-                    help="perf cost-model profile JSON (default: the "
-                         "config's profile_path if present) — drives "
-                         "encode dispatch and micro-batch sizing")
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()

@@ -14,7 +14,7 @@ Three entry modes:
     capped backoff, ``elastic`` folds the logical data-parallel world
     onto whatever devices are alive, and ``--fail-at`` injects a
     deterministic crash to watch it self-heal.  Its result line ends
-    with the loop's counters (docs/DESIGN.md §2.7).
+    with the loop's counters (docs/DESIGN.md §3).
   * ``--mode lm``: trains a (reduced) LM-zoo arch on synthetic tokens
     through the same TrainState/checkpoint machinery (smoke-scale on
     CPU; the full configs are exercised by the dry-run).
@@ -104,11 +104,8 @@ def run_linear(args) -> dict:
     te_acc = accuracy(
         predict_classes(state.params, jnp.asarray(codes_te.astype(np.int32)),
                         lcfg), y_te)
-    from repro import perf
-    rep = perf.dispatch_report()
     print(f"final loss={np.mean(losses[-10:]):.4f} test_acc={te_acc:.4f} "
-          f"stragglers={len(watchdog.flagged_steps)} "
-          f"dispatch_hits={rep['hits']} fallbacks={rep['fallbacks']}")
+          f"stragglers={len(watchdog.flagged_steps)}")
     return dict(test_acc=te_acc, final_loss=float(np.mean(losses[-10:])),
                 steps=int(min(total_steps, step + 1)))
 
@@ -188,8 +185,6 @@ def run_stream(args) -> dict:
     faults.disarm()
     res = sup.result
     n_rows = sum(shard_row_counts(hashed_dir))
-    from repro import perf
-    rep = perf.dispatch_report()
     print(f"streamed {n_rows} rows x {args.epochs} epochs in "
           f"{res.train_seconds:.1f}s: progressive_acc="
           f"{res.progressive_acc:.4f} steps={res.n_steps} "
@@ -197,7 +192,6 @@ def run_stream(args) -> dict:
           f"stragglers={sup.straggler_escalations} "
           f"topology={res.topology_lineage} "
           f"dispatch={res.dispatch} "
-          f"(profile_hits={rep['hits']} fallbacks={rep['fallbacks']}) "
           f"counters={res.counters}")
     return dict(progressive_acc=res.progressive_acc,
                 steps=res.n_steps, restarts=sup.restarts,
@@ -290,25 +284,10 @@ def main() -> None:
                          "gang-restart supervision; CPU only (set "
                          "XLA_FLAGS for fake devices per rank) — on "
                          "chips use --data-parallel")
-    ap.add_argument("--profile", default=None,
-                    help="perf cost-model profile JSON (default: the "
-                         "config's profile_path if it exists; missing/"
-                         "mismatched files fall back to the static "
-                         "dispatch heuristics)")
     args = ap.parse_args()
     os.makedirs(args.workdir, exist_ok=True)
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
-    from repro import perf
-    from repro.configs.rcv1_oph import CONFIG
-    profile = args.profile if args.profile is not None \
-        else CONFIG.profile_path
-    if perf.maybe_load_profile(profile):
-        print(f"dispatch: cost-model profile {profile} "
-              f"(table {perf.get_model().table.table_version})")
-    else:
-        print("dispatch: static heuristics (no usable profile; run "
-              "python -m repro.launch.calibrate to measure this box)")
     if args.mode == "linear":
         run_linear(args)
     elif args.mode == "stream":

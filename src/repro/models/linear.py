@@ -16,8 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import perf
-from repro.kernels import ops, ref
+from repro.kernels import dispatch, ops, ref
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,10 +78,9 @@ def _add_bias_jvp(primals, tangents):
 
 def _forced_impl(cfg: BBitLinearConfig, kernel: str, fallback: str
                  ) -> Optional[str]:
-    """Map the config's ``use_kernel`` tri-state onto a perf pin:
+    """Map the config's ``use_kernel`` tri-state onto a dispatch pin:
     'always'→kernel, 'never'→the fallback arm, 'auto'→None (let
-    ``perf.choose`` decide — static TPU heuristic unless a measured
-    profile says otherwise)."""
+    ``dispatch.choose`` decide: the kernel on a TPU)."""
     if cfg.use_kernel == "always" or cfg.use_kernel is True:
         return kernel
     if cfg.use_kernel == "never" or cfg.use_kernel is False:
@@ -95,7 +93,7 @@ def logits_impl(cfg: BBitLinearConfig, rows: Optional[int] = None) -> str:
     shape = {"k": cfg.k, "b": cfg.b, "v": 1 << cfg.b}
     if rows is not None:
         shape["rows"] = int(rows)
-    return perf.choose("logits", shape,
+    return dispatch.choose("logits", shape,
                        impl=_forced_impl(cfg, "kernel", "gather"))
 
 
@@ -105,7 +103,7 @@ def logits_packed_impl(cfg: BBitLinearConfig,
     shape = {"k": cfg.k, "b": cfg.b, "v": 1 << cfg.b}
     if rows is not None:
         shape["rows"] = int(rows)
-    return perf.choose("logits_packed", shape,
+    return dispatch.choose("logits_packed", shape,
                        impl=_forced_impl(cfg, "kernel", "unpack"))
 
 

@@ -5,7 +5,7 @@ query gathers the union of posting lists for the query's bands (any
 shared band ⇒ candidate — collision probability ~R^r for resemblance
 R), then ranks the candidate set by exact packed-popcount Hamming
 similarity through ``ops.hamming_topk`` (Pallas kernel or XLA
-``population_count``, the cost model's call).  Distances are over the
+``population_count``, ``kernels.dispatch``'s call).  Distances are over the
 b-bit codes themselves, so similarity here estimates the paper's code
 agreement P_b, a monotone proxy for resemblance (Eq. 6 regime) —
 ``benchmarks/retrieval_bench.py`` measures recall@k against exact

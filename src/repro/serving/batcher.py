@@ -174,9 +174,7 @@ class BucketBatcher:
     per-item results`` runs on the resolver thread (the blocking
     device→host sync lives here, off the drain loop).
 
-    A lane is drained when it reaches its cap (``lane_caps[key]`` where
-    given — the cost model's measured throughput-optimal micro-batch
-    for that lane — else the global ``max_batch``) or its oldest
+    A lane is drained when it reaches ``max_batch`` or its oldest
     request has waited ``max_wait_ms``; a full lane dispatches
     immediately (never queues behind another lane's not-yet-ripe head),
     otherwise lanes compete oldest-head-first so none starves.  At most
@@ -191,8 +189,7 @@ class BucketBatcher:
                  depth: int = 2,
                  size: Callable[[object], int] = len,
                  watchdog: Optional[StepWatchdog] = None,
-                 stall_after_s: float = 10.0,
-                 lane_caps: Optional[Dict[Hashable, int]] = None):
+                 stall_after_s: float = 10.0):
         if depth < 1:
             raise ValueError(f"pipeline depth must be >= 1, got {depth}")
         self._dispatch = dispatch
@@ -200,7 +197,7 @@ class BucketBatcher:
         self._route = route
         self._size = size
         self.max_batch = max_batch
-        self.lane_caps = dict(lane_caps or {})
+        self._cap = max(1, int(max_batch))     # a lane's drain size
         self.max_wait = max_wait_ms / 1000.0
         self.depth = depth
         self._cond = threading.Condition()
@@ -295,10 +292,6 @@ class BucketBatcher:
                 "slow_batches": len(self.watchdog.flagged_steps),
                 "escalations": len(self.watchdog.escalations)}
 
-    def _lane_cap(self, key) -> int:
-        cap = self.lane_caps.get(key, self.max_batch)
-        return max(1, min(int(cap), self.max_batch))
-
     def _pick_locked(self):
         """→ (key, head_enq_time, full) or None.  A FULL lane (≥ its
         cap) wins outright — it is dispatchable NOW and must not
@@ -308,7 +301,7 @@ class BucketBatcher:
         for key, lane in self._lanes.items():
             if not lane:
                 continue
-            if len(lane) >= self._lane_cap(key):
+            if len(lane) >= self._cap:
                 return (key, lane[0][2], True)
             if best is None or lane[0][2] < best[1]:
                 best = (key, lane[0][2], False)
@@ -332,7 +325,7 @@ class BucketBatcher:
                             or t_head <= self._flush_before):
                         batch = [lane.popleft() for _ in
                                  range(min(len(lane),
-                                           self._lane_cap(key)))]
+                                           self._cap))]
                         break
                     # head not ripe: sleep at most until it is (an
                     # incoming submit notifies earlier)

@@ -8,7 +8,7 @@ on host round-trips and padding.  This engine serves raw sparse
 documents through ONE fused device dispatch per micro-batch:
 
   raw idx/nnz ─▶ scheme.encode_packed_jit (hash → b-bit → pack; Pallas
-  kernel on TPU, XLA elsewhere — ``perf.choose`` via
+  kernel on TPU, XLA elsewhere — ``kernels.dispatch.choose`` via
   ``ops.fused_encode_on_device``)
   ─▶ bbit_scores_packed (packed-input logits kernels) ─▶ scores
 
@@ -71,16 +71,16 @@ from __future__ import annotations
 import threading
 import time
 from concurrent.futures import Future
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from repro import perf
 from repro.core.schemes import make_scheme
 from repro.data.packing import bucket_width, pad_rows
+from repro.kernels import dispatch
 from repro.launch.mesh import make_replica_mesh
 from repro.models.linear import (BBitLinearConfig, bbit_scores,
                                  bbit_scores_packed)
@@ -166,29 +166,10 @@ class HashedClassifierEngine:
         self.nnz_buckets = tuple(sorted(int(b) for b in nnz_buckets))
         if not self.nnz_buckets:
             raise ValueError("need at least one nnz bucket")
-        # per-nnz-lane row buckets + drain caps from the measured
-        # serve_score cost curve (perf profile); without one — or with
-        # explicit row_buckets — the static pow-2 grid applies to every
-        # lane, exactly the pre-cost-model behavior
-        self._lane_row_buckets: Dict[int, Tuple[int, ...]] = {}
-        self._lane_caps: Dict[int, int] = {}
+        # one pow-2 row grid for every nnz lane
         if row_buckets is None:
             top = bucket_width(max_batch, floor=1)
             row_buckets = tuple(1 << i for i in range(top.bit_length()))
-            suggestion = perf.suggest_row_buckets(
-                cfg.k, cfg.b, scheme, max_batch, self.nnz_buckets)
-            if suggestion:
-                self._lane_row_buckets = {
-                    int(m): tuple(sorted(int(r) for r in rb))
-                    for m, rb in suggestion.items()}
-                row_buckets = tuple(sorted(
-                    {r for rb in self._lane_row_buckets.values()
-                     for r in rb}))
-            caps = perf.suggest_lane_caps(
-                cfg.k, cfg.b, scheme, max_batch, self.nnz_buckets)
-            if caps:
-                self._lane_caps = {int(m): int(c)
-                                   for m, c in caps.items()}
         self.row_buckets = tuple(sorted(int(r) for r in row_buckets))
 
         self.mesh = make_replica_mesh(replicas)
@@ -242,25 +223,17 @@ class HashedClassifierEngine:
             self._dispatch_batch, self._resolve_batch,
             route=lambda doc: self._nnz_bucket(len(doc)),
             max_batch=max_batch, max_wait_ms=max_wait_ms,
-            depth=pipeline_depth, lane_caps=self._lane_caps)
+            depth=pipeline_depth)
 
     # ---------------------------------------------------------- buckets --
     def _nnz_bucket(self, n: int) -> int:
         return _grow_bucket(n, self.nnz_buckets)
 
-    def _row_buckets_for(self, key: Optional[int]) -> Tuple[int, ...]:
-        if key is not None:
-            lane = self._lane_row_buckets.get(int(key))
-            if lane:
-                return lane
-        return self.row_buckets
-
-    def _row_bucket(self, n: int, key: Optional[int] = None) -> int:
-        buckets = self._row_buckets_for(key)
-        for r in buckets:
+    def _row_bucket(self, n: int) -> int:
+        for r in self.row_buckets:
             if n <= r:
                 return r
-        return bucket_width(n, floor=buckets[-1])
+        return bucket_width(n, floor=self.row_buckets[-1])
 
     def _precompile(self) -> None:
         """Compile every (row_bucket, nnz_bucket, replica) lane shape up
@@ -278,8 +251,7 @@ class HashedClassifierEngine:
             for m in nnz_buckets:
                 idx = jax.device_put(np.zeros((1, m), np.int32), dev)
                 nnz = jax.device_put(np.ones((1,), np.int32), dev)
-                lane_rows = self._lane_row_buckets.get(int(m))
-                for r in (lane_rows if lane_rows else row_buckets):
+                for r in row_buckets:
                     if (r, m, d) in self._compiled:
                         continue
                     ib = jnp.broadcast_to(idx, (r, m))
@@ -339,7 +311,7 @@ class HashedClassifierEngine:
         against one version even if a reload lands mid-flight."""
         w = self._weights if weights is None else weights
         n = len(docs)
-        rows = self._row_bucket(n, key)
+        rows = self._row_bucket(n)
         # pad_rows owns the id-folding policy (indices ≥ 2^31 fold to
         # [0, 2^31), same as training-side preprocessing) — only the
         # row/width padding to the lane's bucket shape happens here
@@ -658,12 +630,9 @@ class HashedClassifierEngine:
             pipeline_depth=depths["depth"],
             nnz_buckets=list(self.nnz_buckets),
             row_buckets=list(self.row_buckets),
-            lane_row_buckets={str(m): list(rb) for m, rb
-                              in self._lane_row_buckets.items()},
-            lane_caps={str(m): c for m, c in self._lane_caps.items()},
             rebuckets=self.rebuckets,
             health=self.batcher.health(),
-            dispatch=perf.dispatch_report(),
+            dispatch=dispatch.dispatch_report(),
             dedup=(dict(self.dedup.stats(), enabled=True,
                         rows_per_band=self._dedup_rows_per_band,
                         probe_bands=self._dedup_probe_bands)

@@ -114,7 +114,6 @@ from repro.data.prefetch import (
     Boundary, InlineStream, StreamBatch, ThreadedPrefetcher,
     group_batch_stream, serial_batch_stream, shard_order,
 )
-from repro import perf
 from repro.perf.spans import span, step_span
 from repro.models.linear import (
     BBitLinearConfig, bbit_logits_packed, init_bbit_linear,
@@ -158,11 +157,10 @@ class StreamFitResult:
     # under, oldest first — the sanctioned topology-lineage record
     # also stored in each checkpoint's meta.json
     topology_lineage: list = dataclasses.field(default_factory=list)
-    # what the cost-model dispatch actually ran (impl per op + profile
-    # identity) — recorded in each checkpoint's meta.json extras too,
-    # NOT in the replay fingerprint (a profile swap must not invalidate
-    # a resume; the numerics are impl-invariant within tolerance and
-    # bit-identical on the packed-kernel/unpack pair used here)
+    # the arm the dispatch ran (``{"logits_packed": impl}``) — recorded
+    # in each checkpoint's meta.json extras too, NOT in the replay
+    # fingerprint (a resume under another arm stays valid; the numerics
+    # are impl-invariant within tolerance)
     dispatch: Optional[dict] = None
     # what the loop did at its boundaries: ``device_reads`` (arrays read
     # device → host: hit drains, checkpoint and published leaves, the
@@ -565,14 +563,12 @@ def fit_streaming(
         # world is part of the key: the same logical schedule folds into
         # differently-shaped per-device programs on different meshes.
         # resolve the packed-logits dispatch ONCE, up front: it pins the
-        # trace (part of the step-cache key — a profile loaded between two
-        # fits must not reuse a step traced for the other impl) and is the
-        # run's dispatch-of-record in checkpoints + StreamFitResult
+        # trace (part of the step-cache key — a pin or REPRO_DISPATCH
+        # change between two fits must not reuse a step traced for the
+        # other impl) and is the run's dispatch-of-record in checkpoints
+        # + StreamFitResult
         chosen_impl = logits_packed_impl(cfg, rows=batch_size)
-        _perf_rep = perf.dispatch_report()
-        dispatch_record = {"logits_packed": chosen_impl,
-                           "table_version": _perf_rep["table_version"],
-                           "profile_loaded": _perf_rep["profile_loaded"]}
+        dispatch_record = {"logits_packed": chosen_impl}
 
         step_key = ("dp" if dp else "serial", logical, physical, procs,
                     cfg, has_empty, loss, optimizer, lr, l2, chosen_impl,

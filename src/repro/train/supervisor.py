@@ -50,6 +50,8 @@ import sys
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from repro.ft.retry import BackoffPolicy
 from repro.ft.watchdog import StepWatchdog
 from repro.train.streaming import StreamFitResult, fit_streaming
@@ -248,8 +250,7 @@ def run_multiprocess_supervised(
     """
     if procs < 1:
         raise ValueError(f"procs must be >= 1, got {procs}")
-    from repro import perf
-    backend = perf.device_fingerprint()["backend"]
+    backend = jax.default_backend()
     if procs > 1 and backend != "cpu":
         raise ValueError(
             f"a {procs}-process gang on one {backend} host cannot share "

@@ -15,7 +15,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 import chip_smoke  # noqa: E402
-from repro import perf  # noqa: E402
+from repro.kernels import dispatch  # noqa: E402
 
 TINY = chip_smoke.Sizes(n_docs=96, held_out=16, check_docs=12,
                         serve_docs=8, k=16, b=4, batch=8)
@@ -23,11 +23,10 @@ TINY = chip_smoke.Sizes(n_docs=96, held_out=16, check_docs=12,
 
 @pytest.fixture(autouse=True)
 def _clean_dispatch(monkeypatch):
-    monkeypatch.delenv(perf.ENV_DISPATCH, raising=False)
-    monkeypatch.delenv(perf.ENV_PROFILE, raising=False)
-    perf.reset()
+    monkeypatch.delenv(dispatch.ENV_DISPATCH, raising=False)
+    dispatch.reset()
     yield
-    perf.reset()
+    dispatch.reset()
 
 
 def test_phases_end_to_end_tiny(tmp_path):
@@ -52,7 +51,7 @@ def test_phases_end_to_end_tiny(tmp_path):
 def test_hash_and_train_on_kernel_arms(tmp_path, monkeypatch):
     """The Pallas arms (interpret mode here) pass the same checks, and
     the records name them."""
-    monkeypatch.setenv(perf.ENV_DISPATCH,
+    monkeypatch.setenv(dispatch.ENV_DISPATCH,
                        "encode_packed=pallas,logits_packed=kernel")
     rec, (_rows, _labels, root) = chip_smoke.phase_hash(
         str(tmp_path), TINY, 0, require_kernels=False)

@@ -399,9 +399,7 @@ def test_multiprocess_refused_on_chip_backend(archive, tmp_path,
                                                monkeypatch):
     """A one-host gang cannot share chips this process already holds:
     on any non-CPU backend the launcher points at data_parallel."""
-    from repro import perf
-    monkeypatch.setattr(perf, "device_fingerprint",
-                        lambda: {"backend": "tpu"})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(ValueError, match="--data-parallel 2"):
         run_multiprocess_supervised(
             archive, CFG, procs=2, run_dir=str(tmp_path),

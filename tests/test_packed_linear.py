@@ -204,10 +204,10 @@ def test_packed_b16_kernels_match_reference(n, k, c, masked):
 
 
 def test_packed_b16_custom_vjp_takes_the_kernel_arm():
-    from repro import perf
+    from repro.kernels import dispatch
     k, b = 8, 16
     _, packed, weights, _, _ = _case(b, k, n=40, c=1)
-    perf.reset()
+    dispatch.reset()
 
     def loss_kernel(w):
         return jnp.sum(ops.bbit_linear_packed(packed, w, k, b) ** 2)
@@ -215,12 +215,12 @@ def test_packed_b16_custom_vjp_takes_the_kernel_arm():
     def loss_ref(w):
         return jnp.sum(ref.bbit_linear_packed_fwd(packed, w, k, b) ** 2)
 
-    assert ops.packed_kernel_supported(b, 1 << b)
+    assert dispatch.packed_kernel_fits(b, 1 << b)
     jaxpr = jax.make_jaxpr(jax.grad(loss_kernel))(weights)
     assert str(jaxpr).count("pallas_call") == 2      # forward and dW
     g = jax.grad(loss_kernel)(weights)
     chosen = {key: impl for key, impl in
-              perf.dispatch_report()["choices"].items()
+              dispatch.dispatch_report()["choices"].items()
               if key.startswith("logits_packed_bwd|")}
     assert chosen and all("b=16" in key and impl == "kernel"
                           for key, impl in chosen.items()), chosen

@@ -5,14 +5,13 @@ extraction straight from the packed bitstream (bit-exact vs the
 unpacked reference, including non-byte-aligned b·r), the banded
 inverted index's insert/query/delete lifecycle, and the
 ``hamming_topk`` op — Pallas vs XLA parity, dispatch-report presence,
-and the loud ineligible-force fallback shared by every dispatched op.
+and the ineligible-force fallback shared by every dispatched op.
 """
 import numpy as np
 import pytest
 
-import repro.perf as perf
 from repro.core.bbit import pack_codes, packed_width
-from repro.kernels import ops
+from repro.kernels import dispatch, ops
 from repro.kernels.hamming import (hamming_distance_pallas,
                                    hamming_distance_xla)
 from repro.retrieval import BandedLSHIndex
@@ -22,11 +21,10 @@ from repro.retrieval.bands import (band_geometry, band_keys_packed,
 
 @pytest.fixture(autouse=True)
 def _clean_dispatch(monkeypatch):
-    monkeypatch.delenv(perf.ENV_DISPATCH, raising=False)
-    monkeypatch.delenv(perf.ENV_PROFILE, raising=False)
-    perf.reset()
+    monkeypatch.delenv(dispatch.ENV_DISPATCH, raising=False)
+    dispatch.reset()
     yield
-    perf.reset()
+    dispatch.reset()
 
 
 def _codes(n, k, b, seed=0):
@@ -164,17 +162,18 @@ def test_hamming_topk_matches_brute_force():
 
 def test_hamming_topk_in_dispatch_report_with_loud_fallback():
     shape = {"b": 8, "k": 32, "rows": 50, "width": 32}
-    assert perf.choose("hamming_topk", shape) in ("pallas", "xla")
-    rep = perf.dispatch_report()
+    assert dispatch.choose("hamming_topk", shape) in ("pallas", "xla")
+    rep = dispatch.dispatch_report()
     assert any(key.startswith("hamming_topk") for key in rep["choices"])
-    # forcing the Pallas arm on an unpacked-ineligible b is ignored
-    # loudly (counted), not crashed into — same contract as encode
-    before = rep["ineligible_overrides"]
-    got = perf.choose("hamming_topk",
-                      {"b": 3, "k": 32, "rows": 50, "width": 12},
-                      impl="pallas")
+    # forcing the Pallas arm on an unpacked-ineligible b is ignored,
+    # not crashed into — same contract as encode — and the record
+    # names the arm that ran
+    got = dispatch.choose("hamming_topk",
+                          {"b": 3, "k": 32, "rows": 50, "width": 12},
+                          impl="pallas")
     assert got == "xla"
-    assert perf.dispatch_report()["ineligible_overrides"] == before + 1
+    assert dispatch.dispatch_report()["choices"][
+        "hamming_topk|b=3,k=32,rows=64,width=16"] == "xla"
 
 
 def test_index_recall_tracks_brute_force_resemblance():
