@@ -160,6 +160,16 @@ def read_leaves(tree: Any) -> dict:
                 for i, x in enumerate(leaves)}
 
 
+def leaf_crc32(arr: Any) -> int:
+    """CRC32 of a leaf's bytes in C order, read from its own buffer.
+
+    The value ``meta.json`` records (checkpoint format 4), computed
+    without a byte-string copy of the leaf: a C-contiguous leaf is
+    checksummed in place; any other is copied once, to C order.
+    """
+    return zlib.crc32(np.ascontiguousarray(arr).reshape(-1).view(np.uint8))
+
+
 def _write(root: str, step: int, arrays: dict, keep_last: int,
            extra_meta: Optional[dict]) -> str:
     """Serialise, checksum, fsync, rename and prune: ``save`` after
@@ -169,32 +179,34 @@ def _write(root: str, step: int, arrays: dict, keep_last: int,
     tmp = os.path.join(root, f".tmp-{step}")
     os.makedirs(tmp, exist_ok=True)
     payload = os.path.join(tmp, "ckpt.npz")
-    np.savez(payload, **arrays)
+    with span("repro.ckpt.serialize"):
+        np.savez(payload, **arrays)
+    with span("repro.ckpt.checksum"):
+        crcs = {k: leaf_crc32(v) for k, v in arrays.items()}
     meta = {"step": int(step), "n_leaves": len(arrays),
-            "ckpt_format": CKPT_FORMAT,
-            "crc32": {k: zlib.crc32(np.ascontiguousarray(v).tobytes())
-                      for k, v in arrays.items()}}
+            "ckpt_format": CKPT_FORMAT, "crc32": crcs}
     if extra_meta:
         meta.update(extra_meta)
     with open(os.path.join(tmp, "meta.json"), "w") as f:
         json.dump(meta, f)
         f.flush()
         os.fsync(f.fileno())
-    if directive == "torn":
-        # the injected failure mode: the rename becomes durable but the
-        # payload pages never hit disk — model it by truncating AFTER
-        # the write, skipping the payload fsync, and completing the
-        # publication below before crashing
-        size = os.path.getsize(payload)
-        with open(payload, "r+b") as f:
-            f.truncate(max(1, int(size * 0.6)))
-    else:
-        _fsync_path(payload)
-    final = _step_dir(root, step)
-    if os.path.exists(final):
-        shutil.rmtree(final)
-    os.rename(tmp, final)
-    _fsync_path(root)
+    with span("repro.ckpt.fsync"):
+        if directive == "torn":
+            # the injected failure mode: the rename becomes durable but
+            # the payload pages never hit disk — model it by truncating
+            # AFTER the write, skipping the payload fsync, and
+            # completing the publication below before crashing
+            size = os.path.getsize(payload)
+            with open(payload, "r+b") as f:
+                f.truncate(max(1, int(size * 0.6)))
+        else:
+            _fsync_path(payload)
+        final = _step_dir(root, step)
+        if os.path.exists(final):
+            shutil.rmtree(final)
+        os.rename(tmp, final)
+        _fsync_path(root)
 
     manifest = _read_manifest(root)
     steps = sorted(set(manifest.get("steps", [])) | {int(step)})
@@ -257,7 +269,7 @@ def _load_validated(d: str, meta: Optional[dict]) -> dict:
     if crcs:  # format-3 checkpoints predate CRCs: parse-check only
         for name, arr in arrays.items():
             want = crcs.get(name)
-            got = zlib.crc32(np.ascontiguousarray(arr).tobytes())
+            got = leaf_crc32(arr)
             if want is None or int(want) != got:
                 raise CorruptCheckpointError(
                     f"CRC mismatch on {name} (recorded {want}, "

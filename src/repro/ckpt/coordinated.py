@@ -59,7 +59,6 @@ import logging
 import os
 import shutil
 import time
-import zlib
 from typing import Any, Optional
 
 import numpy as np
@@ -100,7 +99,7 @@ def _write_rank_payload(stage: str, rank: int, step: int,
     rename (CRCs were recorded from the in-memory arrays, so restore
     detects the tear), then raises ``InjectedCrash``.
     """
-    from repro.ckpt.checkpoint import _fsync_path
+    from repro.ckpt.checkpoint import _fsync_path, leaf_crc32
 
     directive = faults.on_ckpt_write(step) if faults._ACTIVE is not None \
         else None
@@ -109,27 +108,29 @@ def _write_rank_payload(stage: str, rank: int, step: int,
         shutil.rmtree(tmp)
     os.makedirs(tmp)
     payload = os.path.join(tmp, "ckpt.npz")
-    np.savez(payload, **arrays)
+    with span("repro.ckpt.serialize"):
+        np.savez(payload, **arrays)
+    with span("repro.ckpt.checksum"):
+        crcs = {k: leaf_crc32(v) for k, v in arrays.items()}
     meta = {"rank": int(rank), "step": int(step),
             "n_leaves": len(arrays),
-            "ckpt_format": COORDINATED_FORMAT,
-            "crc32": {k: zlib.crc32(np.ascontiguousarray(v).tobytes())
-                      for k, v in arrays.items()}}
+            "ckpt_format": COORDINATED_FORMAT, "crc32": crcs}
     with open(os.path.join(tmp, "meta.json"), "w") as f:
         json.dump(meta, f)
         f.flush()
         os.fsync(f.fileno())
-    if directive == "torn":
-        size = os.path.getsize(payload)
-        with open(payload, "r+b") as f:
-            f.truncate(max(1, int(size * 0.6)))
-    else:
-        _fsync_path(payload)
-    final = os.path.join(stage, _rank_name(rank))
-    if os.path.exists(final):
-        shutil.rmtree(final)
-    os.rename(tmp, final)
-    _fsync_path(stage)
+    with span("repro.ckpt.fsync"):
+        if directive == "torn":
+            size = os.path.getsize(payload)
+            with open(payload, "r+b") as f:
+                f.truncate(max(1, int(size * 0.6)))
+        else:
+            _fsync_path(payload)
+        final = os.path.join(stage, _rank_name(rank))
+        if os.path.exists(final):
+            shutil.rmtree(final)
+        os.rename(tmp, final)
+        _fsync_path(stage)
     if directive == "torn":
         raise faults.InjectedCrash(
             f"injected torn rank-{rank} checkpoint write at step {step}")

@@ -29,9 +29,12 @@ KW = dict(epochs=1, batch_size=BATCH, lr=5e-3, seed=3,
 
 LOOP = {"repro.train.job_setup", "repro.train.step", "repro.prefetch.wait",
         "repro.train.hits_drain", "repro.ckpt.save", "repro.ckpt.read_device",
-        "repro.ckpt.write", "repro.ckpt.publish", "repro.train.job_end"}
+        "repro.ckpt.write", "repro.ckpt.serialize", "repro.ckpt.checksum",
+        "repro.ckpt.fsync", "repro.ckpt.publish", "repro.train.job_end"}
 PRODUCER = {"repro.prefetch.read", "repro.prefetch.put"}
 READS = ("repro.train.hits_drain", "repro.ckpt.read_device")
+WRITE_PARTS = ("repro.ckpt.serialize", "repro.ckpt.checksum",
+               "repro.ckpt.fsync")
 
 
 @pytest.fixture(scope="module")
@@ -139,13 +142,32 @@ def test_checkpoint_spans_nest(run, request):
         inner = collections.Counter(s[0] for s in loop
                                     if s is not outer and _inside(s, outer))
         # a publish reads the average's count, then saves the snapshot
-        want = {"repro.ckpt.read_device": 1, "repro.ckpt.write": 1}
+        want = {"repro.ckpt.read_device": 1, "repro.ckpt.write": 1,
+                **{name: 1 for name in WRITE_PARTS}}
         if outer[0] == "repro.ckpt.publish":
             want["repro.ckpt.read_device"] = 2
         assert inner == want, outer
     for s in loop:
         if s[0] in ("repro.ckpt.read_device", "repro.ckpt.write"):
             assert any(_inside(s, o) for o in saves + publishes), s
+
+
+@pytest.mark.parametrize("run", ["threaded", "inline"])
+def test_write_spans_split_the_write(run, request):
+    """Each write holds one serialize, checksum and fsync, in that
+    order, and none of them runs outside a write."""
+    _, lines = request.getfixturevalue(run)
+    loop = [s for spans in lines for s in spans if s[0] in LOOP]
+    writes = [s for s in loop if s[0] == "repro.ckpt.write"]
+    assert len(writes) == 2 * (SHARDS // EVERY)    # saves and publishes
+    for w in writes:
+        inner = sorted((s for s in loop
+                        if s is not w and _inside(s, w)),
+                       key=lambda s: s[1])
+        assert [s[0] for s in inner] == list(WRITE_PARTS), w
+    for s in loop:
+        if s[0] in WRITE_PARTS:
+            assert any(_inside(s, w) for w in writes), s
 
 
 @pytest.mark.parametrize("run", ["threaded", "inline"])
